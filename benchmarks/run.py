@@ -19,6 +19,8 @@ import argparse
 import json
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 _SUITE_CHOICES = ["all", "table3", "table4", "table5", "fig1", "fig2",
                   "stiff", "events", "dispatch", "serving", "training", "step"]
 
@@ -39,6 +41,7 @@ def main() -> None:
                         help="also write rows to a JSON file (default: "
                              "BENCH_<suite>.json)")
     opts = parser.parse_args()
+    enable_compile_cache()
     which = opts.suite
     json_path = _DEFAULT_JSON[which] if opts.json == _JSON_AUTO else opts.json
 

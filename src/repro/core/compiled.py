@@ -57,6 +57,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops
 from .drivers import AutoDiffAdjoint, BacksolveAdjoint, _Driver
 from .solution import Grads, Solution
 from .static import freeze, frozen_setattr
@@ -297,6 +298,9 @@ class CompiledSolver:
              atol=None, device=None, cotangent=None) -> tuple:
         return (
             self._driver_key,
+            # The kernel backend is read at trace time: a program traced for
+            # one backend must never serve a call made under another.
+            ops.backend(),
             _f_key(f),
             _tree_key(y0),
             _tree_key(t_eval),
@@ -315,9 +319,9 @@ class CompiledSolver:
                   device=None, cotangent=None) -> tuple:
         """The hashable identity of the compiled program a ``solve`` with
         these arguments (or ``ShapeDtypeStruct`` specs) would dispatch to:
-        (driver static config, dynamics identity, every dynamic argument's
-        shape/dtype class, placement, cotangent class -- ``None`` for forward
-        programs).  Two argument sets with equal keys share one executable.
+        (driver static config, kernel backend, dynamics identity, every
+        dynamic argument's shape/dtype class, placement, cotangent class --
+        ``None`` for forward programs).  Two argument sets with equal keys share one executable.
         The serving layer buckets requests by exactly this key, so a bucket
         never straddles two programs (and forward and gradient requests never
         share a bucket)."""
@@ -593,7 +597,6 @@ def sharded_solve(
     ``atol``/``solver_kw`` build an ``AutoDiffAdjoint``.  The shard-mapped
     program is jitted and cached, so repeated same-shape calls do not retrace.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if solver is None:
@@ -647,7 +650,7 @@ def sharded_solve(
     batch = requested + n_pad
 
     key = (
-        mesh, axis_name, driver_def, _f_key(f),
+        mesh, axis_name, driver_def, ops.backend(), _f_key(f),
         tuple(_tree_key(t) for t in inputs),
     )
     entry = _SHARDED_CACHE.get(key)
@@ -670,9 +673,9 @@ def sharded_solve(
         out_shape = jax.eval_shape(local, *inputs)
         out_specs = jax.tree_util.tree_map(lambda _: P(axis_name), out_shape)
         entry = jax.jit(
-            shard_map(
+            jax.shard_map(
                 local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
         )
         _SHARDED_CACHE.put(key, entry)

@@ -19,19 +19,26 @@ import jax
 from . import ref
 
 _BACKEND = None
+_BACKENDS = ("ref", "pallas", "interpret")
+
+
+def _check(name: str) -> str:
+    if name not in _BACKENDS:
+        raise ValueError(f"unknown kernel backend {name!r}; expected one of {_BACKENDS}")
+    return name
 
 
 def backend() -> str:
+    """The active backend.  Resolved from ``REPRO_KERNEL_BACKEND`` on first
+    use and validated like ``set_backend``: a misspelt value raises instead
+    of latching a backend that every op would then fail to find."""
     global _BACKEND
     if _BACKEND is None:
         choice = os.environ.get("REPRO_KERNEL_BACKEND", "auto")
         if choice == "auto":
             choice = "pallas" if jax.default_backend() == "tpu" else "ref"
-        _BACKEND = choice
+        _BACKEND = _check(choice)
     return _BACKEND
-
-
-_BACKENDS = ("ref", "pallas", "interpret")
 
 
 def set_backend(name: str) -> None:
@@ -39,9 +46,7 @@ def set_backend(name: str) -> None:
     ``ValueError`` on unknown names (an ``assert`` would vanish under
     ``python -O`` and silently route every op through a bogus backend)."""
     global _BACKEND
-    if name not in _BACKENDS:
-        raise ValueError(f"unknown kernel backend {name!r}; expected one of {_BACKENDS}")
-    _BACKEND = name
+    _BACKEND = _check(name)
 
 
 def reset_backend() -> None:

@@ -40,7 +40,6 @@ from . import ref
 
 BB = 8  # batch tile
 BF = 128  # feature tile (lane dimension)
-BN = 128  # eval-point tile
 
 
 def _pad_to(x, axis, mult, value=0):
@@ -213,23 +212,49 @@ def error_norm(err, y0, y1, atol, rtol, *, interpret=False):
 # ------------------------------------------------------------------ interp
 
 
+BN = 128  # eval-point tile of the feature-lane interp layout
+IB = 64  # row tile of the flattened interp layout
+IL = 512  # lane tile over its flattened (point, feature) axis
+
+
 def _interp_kernel(c0_ref, c1_ref, c2_ref, c3_ref, x_ref, m_ref, prev_ref, out_ref):
+    """Feature-lane layout: (BB, BF) coefficient tiles, (BB, BN) points."""
     x = x_ref[...][:, :, None]  # (BB, BN, 1)
     c0 = c0_ref[...][:, None, :]  # (BB, 1, BF)
     c1 = c1_ref[...][:, None, :]
     c2 = c2_ref[...][:, None, :]
     c3 = c3_ref[...][:, None, :]
     acc = ((c3 * x + c2) * x + c1) * x + c0  # Horner
-    out_ref[...] = jnp.where(m_ref[...][:, :, None], acc, prev_ref[...])
+    m = m_ref[...][:, :, None] != 0  # int32 mask: Mosaic cannot reshape bool vectors
+    out_ref[...] = jnp.where(m, acc, prev_ref[...])
+
+
+def _interp_flat_kernel(c0_ref, c1_ref, c2_ref, c3_ref, x_ref, m_ref, prev_ref, out_ref):
+    """Flattened layout: every operand already spans the (point, feature) axis."""
+    x = x_ref[...]
+    acc = ((c3_ref[...] * x + c2_ref[...]) * x + c1_ref[...]) * x + c0_ref[...]  # Horner
+    out_ref[...] = jnp.where(m_ref[...] != 0, acc, prev_ref[...])
 
 
 def interp_eval(coeffs, x, mask, out, *, interpret=False):
-    c0, c1, c2, c3 = coeffs
+    """Two layouts, chosen by how much padding f to the lane tile costs.
+
+    Features on the lane axis keep the per-point and per-feature operands
+    compact, but pad the (b, n, f) buffer to 128 lanes: 64x the buffer at
+    f = 2, more than a chip holds for a large dense-output batch.  So while
+    that padding would more than quadruple the buffer, the kernel instead
+    sees (b, n * f) with the per-point and per-feature operands broadcast
+    onto the flattened axis outside it (four more buffer-sized operands),
+    and is a plain elementwise select.  Either way the mask travels as
+    int32: Mosaic cannot reshape or widen bool vectors.
+    """
     b, n = x.shape
-    f = c0.shape[1]
-    cs = [_pad_to(_pad_to(c, 0, BB), 1, BF) for c in (c0, c1, c2, c3)]
+    f = coeffs[0].shape[1]
+    if _cdiv(f, BF) * BF > 4 * f:
+        return _interp_eval_flat(coeffs, x, mask, out, interpret=interpret)
+    cs = [_pad_to(_pad_to(c, 0, BB), 1, BF) for c in coeffs]
     xp = _pad_to(_pad_to(x, 0, BB), 1, BN)
-    mp = _pad_to(_pad_to(mask, 0, BB), 1, BN)
+    mp = _pad_to(_pad_to(mask.astype(jnp.int32), 0, BB), 1, BN)
     outp = _pad_to(_pad_to(_pad_to(out, 0, BB), 1, BN), 2, BF)
     bp, np_ = xp.shape
     fp = cs[0].shape[1]
@@ -247,9 +272,34 @@ def interp_eval(coeffs, x, mask, out, *, interpret=False):
         ],
         out_specs=pl.BlockSpec((BB, BN, BF), lambda i, j, k: (i, j, k)),
         out_shape=jax.ShapeDtypeStruct(outp.shape, out.dtype),
+        input_output_aliases={6: 0},
         interpret=interpret,
     )(*cs, xp, mp, outp)
     return res[:b, :n, :f]
+
+
+def _interp_eval_flat(coeffs, x, mask, out, *, interpret):
+    b, n = x.shape
+    f = coeffs[0].shape[1]
+
+    def flat(a):  # (b, n * f), padded to whole (IB, IL) tiles
+        return _pad_to(_pad_to(a.reshape(b, n * f), 0, IB), 1, IL)
+
+    cs = [flat(jnp.broadcast_to(c[:, None, :], (b, n, f))) for c in coeffs]
+    xp = flat(jnp.broadcast_to(x[:, :, None], (b, n, f)))
+    mp = flat(jnp.broadcast_to(mask[:, :, None], (b, n, f)).astype(jnp.int32))
+    outp = flat(out)
+    spec = pl.BlockSpec((IB, IL), lambda i, j: (i, j))
+    res = pl.pallas_call(
+        _interp_flat_kernel,
+        grid=(outp.shape[0] // IB, outp.shape[1] // IL),
+        in_specs=[spec] * 7,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(outp.shape, out.dtype),
+        input_output_aliases={6: 0},
+        interpret=interpret,
+    )(*cs, xp, mp, outp)
+    return res[:b, : n * f].reshape(b, n, f)
 
 
 # ------------------------------------------------------ masked bisect refine
@@ -322,44 +372,51 @@ def _linsolve_kernel(a_ref, b_ref, x_ref, *, n):
     One program owns BB instances and their full (R, C) matrices in VMEM
     (R = rows padded to the 8-sublane layout, C = columns padded to the
     128-lane layout -- stiff ODE systems are small, so rows are NOT padded
-    to a full lane multiple).  Row selection/swap is done with one-hot masks
-    (no dynamic gathers), the pivot search with a max-reduction + first-match
-    instead of argmax, so every op vectorizes.  Only the true n columns are
-    eliminated: the padded block is an identity that never mixes with real
-    rows.
+    to a full lane multiple).  Row and column selection is done with one-hot
+    masked reductions (Mosaic lowers no dynamic gathers or value slices), the
+    pivot search with a max-reduction + first-match instead of argmax, so
+    every op vectorizes.  Only the true n columns are eliminated: the padded
+    block is an identity that never mixes with real rows.
     """
     A = a_ref[...]  # (BB, R, C)
     rhs = b_ref[...]  # (BB, R)
-    R = A.shape[1]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (A.shape[0], R), 1)  # (BB, R)
+    bt, R, C = A.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)  # (BB, R)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, C), 1)  # (BB, C)
+    row3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 1)
+    col3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 2)
 
     def body(i, carry):
         A, rhs = carry
-        col = jax.lax.dynamic_slice_in_dim(A, i, 1, axis=2)[..., 0]  # (BB, R)
+        col = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
         mag = jnp.where(rows >= i, jnp.abs(col), -1.0)
         m = jnp.max(mag, axis=1, keepdims=True)
         cand = mag == m
         p = jnp.min(jnp.where(cand, rows, R), axis=1, keepdims=True)  # (BB, 1)
         is_i = rows == i
         is_p = rows == p
-        Ai = jnp.sum(jnp.where(is_i[:, :, None], A, 0.0), axis=1)  # (BB, C)
-        Ap = jnp.sum(jnp.where(is_p[:, :, None], A, 0.0), axis=1)
+        # (R, C)-shaped row masks come from the 3-D iota: Mosaic cannot
+        # reshape a bool vector, so is_i[:, :, None] does not lower.
+        is_i3 = row3 == i
+        is_p3 = row3 == p[:, :, None]
+        Ai = jnp.sum(jnp.where(is_i3, A, 0.0), axis=1)  # (BB, C)
+        Ap = jnp.sum(jnp.where(is_p3, A, 0.0), axis=1)
         bi = jnp.sum(jnp.where(is_i, rhs, 0.0), axis=1, keepdims=True)  # (BB, 1)
         bp = jnp.sum(jnp.where(is_p, rhs, 0.0), axis=1, keepdims=True)
         # swap rows i <-> p (no-op when p == i: is_i wins and Ap == Ai)
         A = jnp.where(
-            is_i[:, :, None], Ap[:, None, :], jnp.where(is_p[:, :, None], Ai[:, None, :], A)
+            is_i3, Ap[:, None, :], jnp.where(is_p3, Ai[:, None, :], A)
         )
         rhs = jnp.where(is_i, bp, jnp.where(is_p, bi, rhs))
         # normalize the pivot row, eliminate column i from every other row
-        piv = jax.lax.dynamic_slice_in_dim(Ap, i, 1, axis=1)  # (BB, 1)
+        piv = jnp.sum(jnp.where(cols == i, Ap, 0.0), axis=1, keepdims=True)  # (BB, 1)
         prow = Ap / piv
         pb = bp / piv
-        colnew = jax.lax.dynamic_slice_in_dim(A, i, 1, axis=2)[..., 0]  # (BB, R)
+        colnew = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
         factor = jnp.where(is_i, 0.0, colnew)
         A = A - factor[:, :, None] * prow[:, None, :]
         rhs = rhs - factor * pb
-        A = jnp.where(is_i[:, :, None], prow[:, None, :], A)
+        A = jnp.where(is_i3, prow[:, None, :], A)
         rhs = jnp.where(is_i, pb, rhs)
         return A, rhs
 
@@ -400,35 +457,41 @@ def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
     """Partial-pivoted LU factorization, vectorized over the batch tile.
 
     Same memory plan as ``_linsolve_kernel`` (one program owns BB instances
-    with the full (R, C) matrix in VMEM, one-hot row extraction/swap, pivot
-    by max-reduction + first-match), but instead of eliminating a right-hand
-    side it stores the factors in place -- the unit-lower multipliers below
-    the diagonal, U on and above -- and tracks the row permutation as a
-    (BB, R) int32 vector (entry swaps mirror the row swaps).  This runs ONCE
-    per implicit solver step; every ``fused_newton_iter`` launch then
-    back-substitutes against the stored factors, which is what turns the
-    per-iteration O(n^3) elimination into O(n^2) triangular solves.
+    with the full (R, C) matrix in VMEM, one-hot row/column extraction and
+    swap, pivot by max-reduction + first-match), but instead of eliminating
+    a right-hand side it stores the factors in place -- the unit-lower
+    multipliers below the diagonal, U on and above -- and tracks the row
+    permutation as a (BB, R) int32 vector (entry swaps mirror the row
+    swaps).  This runs ONCE per implicit solver step; every
+    ``fused_newton_iter`` launch then back-substitutes against the stored
+    factors, which is what turns the per-iteration O(n^3) elimination into
+    O(n^2) triangular solves.
     """
     A = a_ref[...]  # (BB, R, C)
     bt, R, C = A.shape
     rows = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)  # (BB, R)
-    row3 = jax.lax.broadcasted_iota(jnp.int32, (bt, R, C), 1)
-    col3 = jax.lax.broadcasted_iota(jnp.int32, (bt, R, C), 2)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, C), 1)  # (BB, C)
+    row3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 1)
+    col3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 2)
 
     def body(i, carry):
         A, perm = carry
-        col = jax.lax.dynamic_slice_in_dim(A, i, 1, axis=2)[..., 0]  # (BB, R)
+        col = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
         mag = jnp.where(rows >= i, jnp.abs(col), -1.0)
         m = jnp.max(mag, axis=1, keepdims=True)
         cand = mag == m
         p = jnp.min(jnp.where(cand, rows, R), axis=1, keepdims=True)  # (BB, 1)
         is_i = rows == i
         is_p = rows == p
-        Ai = jnp.sum(jnp.where(is_i[:, :, None], A, 0.0), axis=1)  # (BB, C)
-        Ap = jnp.sum(jnp.where(is_p[:, :, None], A, 0.0), axis=1)
+        # (R, C)-shaped row masks come from the 3-D iota: Mosaic cannot
+        # reshape a bool vector, so is_i[:, :, None] does not lower.
+        is_i3 = row3 == i
+        is_p3 = row3 == p[:, :, None]
+        Ai = jnp.sum(jnp.where(is_i3, A, 0.0), axis=1)  # (BB, C)
+        Ap = jnp.sum(jnp.where(is_p3, A, 0.0), axis=1)
         # swap rows i <-> p (no-op when p == i: is_i wins and Ap == Ai)
         A = jnp.where(
-            is_i[:, :, None], Ap[:, None, :], jnp.where(is_p[:, :, None], Ai[:, None, :], A)
+            is_i3, Ap[:, None, :], jnp.where(is_p3, Ai[:, None, :], A)
         )
         # dtype pinned: under x64 jnp.sum would promote int32 -> int64 and
         # break the fori_loop carry contract
@@ -438,8 +501,8 @@ def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
                      dtype=jnp.int32)
         perm = jnp.where(is_i, pp, jnp.where(is_p, pi, perm))
         # multipliers below the diagonal; eliminate only the trailing columns
-        piv = jax.lax.dynamic_slice_in_dim(Ap, i, 1, axis=1)  # (BB, 1)
-        colnew = jax.lax.dynamic_slice_in_dim(A, i, 1, axis=2)[..., 0]
+        piv = jnp.sum(jnp.where(cols == i, Ap, 0.0), axis=1, keepdims=True)  # (BB, 1)
+        colnew = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
         factor = jnp.where(rows > i, colnew / piv, 0.0)  # (BB, R)
         A = A - jnp.where(col3 > i, factor[:, :, None] * Ap[:, None, :], 0.0)
         # store the multipliers in place of the eliminated column entries
@@ -484,7 +547,7 @@ def batched_lu_factor(A, *, interpret=False):
 
 
 def _newton_iter_kernel(
-    lu_ref, perm_ref, k_ref, fk_ref, act_ref, scale_ref, k_out, res_out,
+    lut_ref, perm_ref, k_ref, fk_ref, act_ref, scale_ref, k_out, res_out,
     *, n, n_feat,
 ):
     """One whole chord-Newton iteration against the prefactored LU, as ONE
@@ -493,27 +556,26 @@ def _newton_iter_kernel(
     scaled-RMS convergence norm -- the fusion of ``batched_linsolve`` +
     ``masked_newton_update`` with the elimination already paid for.
 
-    Substitution is COLUMN-oriented: each fori iteration pulls one factor
-    column with a lane-axis ``dynamic_slice`` (cheap; the sublane axis never
-    needs dynamic indexing) and does O(R) vector work, so a whole triangular
-    solve is O(n^2) -- this is what makes the per-iteration launch strictly
-    cheaper than the O(n^3) elimination it replaces.  The padded tail never
-    mixes in: padded residual entries are 0 and real-row padded-column
-    factors are 0.
+    Substitution is COLUMN-oriented against the TRANSPOSED factors: factor
+    column j is row j of ``lut_ref``, a dynamic sublane-axis load from VMEM
+    (Mosaic lowers no lane-axis dynamic slice), and each fori iteration does
+    O(R) vector work, so a whole triangular solve is O(n^2) -- this is what
+    makes the per-iteration launch strictly cheaper than the O(n^3)
+    elimination it replaces.  The padded tail never mixes in: padded
+    residual entries are 0 and real-row padded-column factors are 0.
     """
-    LU = lu_ref[...]  # (BB, R, C)
-    bt, R, _ = LU.shape
+    bt, _, R = lut_ref.shape
     perm = perm_ref[...]  # (BB, R) int32
     k = k_ref[...]  # (BB, R)
     g = k - fk_ref[...]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)
-    src3 = jax.lax.broadcasted_iota(jnp.int32, (bt, R, R), 2)
+    src3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, R), 2)
 
     # permutation row-gather: x[r] = g[perm[r]] (one-hot, no dynamic gathers)
     x = jnp.sum(jnp.where(perm[:, :, None] == src3, g[:, None, :], 0.0), axis=2)
 
-    def col_of(j):
-        return jax.lax.dynamic_slice_in_dim(LU, j, 1, axis=2)[..., 0]  # (BB, R)
+    def col_of(j):  # factor column j, (BB, R)
+        return lut_ref[:, pl.ds(j, 1), :][:, 0, :]
 
     def at(j, v):  # extract entry j of a (BB, R) vector as (BB, 1)
         return jnp.sum(jnp.where(rows == j, v, 0.0), axis=1, keepdims=True)
@@ -531,7 +593,7 @@ def _newton_iter_kernel(
 
     delta = jax.lax.fori_loop(0, n, bwd, x)
 
-    active = act_ref[...]  # (BB, 1) bool
+    active = act_ref[...] != 0  # (BB, 1)
     k_out[...] = jnp.where(active, k - delta, k)
     r = delta / scale_ref[...]
     res_out[...] = jnp.sqrt(jnp.sum(r * r, axis=1, keepdims=True) / n_feat)
@@ -540,16 +602,18 @@ def _newton_iter_kernel(
 def fused_newton_iter(lu, perm, k, fk, active, scale, *, interpret=False):
     b, f = k.shape
     scale = jnp.broadcast_to(jnp.asarray(scale, k.dtype), (b, f))
-    lup = _pad_to(_pad_to(_pad_to(lu, 0, BB), 1, BB), 2, BF)
-    bp_, fr, fc = lup.shape
+    # Both factor axes pad to the 8-sublane layout: the kernel reads factor
+    # columns as sublane rows of the transpose.
+    lup = _pad_to(_pad_to(_pad_to(lu, 0, BB), 1, BB), 2, BB)
+    bp_, fr, _ = lup.shape
     # Re-seat the padded diagonal (the wrapper contract is the sliced true
     # factors) so the backward substitution never divides by a padded zero
     # on real batch rows; padded residual entries are 0 either way.
     pad_eye = (
-        (jnp.arange(fr)[:, None] == jnp.arange(fc)[None, :])
+        (jnp.arange(fr)[:, None] == jnp.arange(fr)[None, :])
         & (jnp.arange(fr)[:, None] >= f)
     ).astype(lu.dtype)
-    lup = lup + pad_eye[None, :, :]
+    lut = jnp.swapaxes(lup + pad_eye[None, :, :], 1, 2)
     ids = jnp.arange(fr, dtype=perm.dtype)
     permp = _pad_to(_pad_to(perm, 0, BB), 1, BB)
     permp = jnp.where(ids[None, :] >= f, ids[None, :], permp)
@@ -558,12 +622,12 @@ def fused_newton_iter(lu, perm, k, fk, active, scale, *, interpret=False):
     kp = _pad_to(_pad_to(k, 0, BB), 1, BB)
     fkp = _pad_to(_pad_to(fk, 0, BB), 1, BB)
     sp = _pad_to(_pad_to(scale, 0, BB, value=1), 1, BB, value=1)
-    ap = _pad_to(active[:, None], 0, BB)
+    ap = _pad_to(active.astype(jnp.int32)[:, None], 0, BB)
     k_new, res = pl.pallas_call(
         functools.partial(_newton_iter_kernel, n=f, n_feat=float(f)),
         grid=(bp_ // BB,),
         in_specs=[
-            pl.BlockSpec((BB, fr, fc), lambda i: (i, 0, 0)),
+            pl.BlockSpec((BB, fr, fr), lambda i: (i, 0, 0)),
             pl.BlockSpec((BB, fr), lambda i: (i, 0)),
             pl.BlockSpec((BB, fr), lambda i: (i, 0)),
             pl.BlockSpec((BB, fr), lambda i: (i, 0)),
@@ -579,7 +643,7 @@ def fused_newton_iter(lu, perm, k, fk, active, scale, *, interpret=False):
             jax.ShapeDtypeStruct((bp_, 1), k.dtype),
         ],
         interpret=interpret,
-    )(lup, permp, kp, fkp, ap, sp)
+    )(lut, permp, kp, fkp, ap, sp)
     return k_new[:b, :f], res[:b, 0]
 
 
@@ -1125,18 +1189,20 @@ def _event_detect_kernel(
 ):
     v0 = vp_ref[...]  # (BB, E)
     v1 = vn_ref[...]
-    accept = acc_ref[...]  # (BB, 1), broadcasts over E
+    accept = acc_ref[...] != 0  # (BB, 1), broadcasts over E
     up = (v0 <= 0.0) & (v1 >= 0.0)
     down = (v0 >= 0.0) & (v1 <= 0.0)
     # Per-event direction choice unrolled over the static tuple (a materialized
-    # direction vector would be a captured constant, which pallas forbids).
-    cols = []
+    # direction vector would be a captured constant, which pallas forbids),
+    # masked by lane index with logical ops only: Mosaic can neither
+    # concatenate nor select between bool vectors.
+    lane = jax.lax.broadcasted_iota(jnp.int32, v0.shape, 1)
+    crossed = None
     for i, d in enumerate(directions):
-        c = up if d > 0 else down if d < 0 else up | down
-        cols.append(c[:, i:i + 1])
-    crossed = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+        c = (lane == i) & (up if d > 0 else down if d < 0 else up | down)
+        crossed = c if crossed is None else crossed | c
     crossed = crossed & ((v0 != 0.0) | (v1 != 0.0))
-    newly = crossed & ~fired_ref[...] & accept
+    newly = crossed & (fired_ref[...] == 0) & accept
     newly_out[...] = newly.astype(jnp.int32)
     vkeep_out[...] = jnp.where(accept, v1, v0)
 
@@ -1145,8 +1211,8 @@ def fused_event_detect(v_prev, v_new, fired, accept, *, directions, interpret=Fa
     b, E = v_prev.shape
     vpp = _pad_to(v_prev, 0, BB)
     vnp_ = _pad_to(v_new, 0, BB)
-    firedp = _pad_to(fired, 0, BB)
-    accp = _pad_to(accept[:, None], 0, BB)
+    firedp = _pad_to(fired.astype(jnp.int32), 0, BB)
+    accp = _pad_to(accept.astype(jnp.int32)[:, None], 0, BB)
     bp = vpp.shape[0]
     espec = pl.BlockSpec((BB, E), lambda i: (i, 0))
     cspec = pl.BlockSpec((BB, 1), lambda i: (i, 0))
@@ -1173,7 +1239,7 @@ def _event_commit_kernel(
     *, terminal,
 ):
     x = x_ref[...]  # (BB, E)
-    newly = newly_ref[...]
+    newly = newly_ref[...] != 0  # int32 in: bool columns do not lower
     t0 = t0_ref[...]  # (BB, 1)
     dt = dt_ref[...]
     yev = yev_ref[...]  # (BB, E, BF) feature tile
@@ -1185,7 +1251,7 @@ def _event_commit_kernel(
     for i, term in enumerate(terminal):
         if not term:
             continue
-        n_i = newly[:, i:i + 1]  # (BB, 1)
+        n_i = newly_ref[:, i:i + 1] != 0  # (BB, 1)
         stop = stop | n_i
         earlier = n_i & (x[:, i:i + 1] < x_stop)
         y_stop = jnp.where(earlier, yev[:, i, :], y_stop)
@@ -1193,9 +1259,10 @@ def _event_commit_kernel(
     rec = newly & (x <= x_stop)  # (BB, E)
     # The E-column and scalar-column outputs do not depend on the feature
     # tile; rewriting them once per tile is idempotent (bisect-kernel rule).
-    fired_out[...] = (fired_ref[...] | rec).astype(jnp.int32)
+    fired_out[...] = ((fired_ref[...] != 0) | rec).astype(jnp.int32)
     evt_out[...] = jnp.where(rec, t0 + x * dt, evt_ref[...])
-    evy_out[...] = jnp.where(rec[:, :, None], yev, evy_ref[...])
+    rec3 = rec.astype(jnp.int32)[:, :, None] != 0  # widen as int32, not bool
+    evy_out[...] = jnp.where(rec3, yev, evy_ref[...])
     stop_out[...] = stop.astype(jnp.int32)
     tstop_out[...] = t0 + jnp.where(stop, x_stop, 0.0) * dt
     ystop_out[...] = y_stop
@@ -1209,11 +1276,11 @@ def fused_event_commit(
     f = y_new.shape[1]
     xp = _pad_to(x, 0, BB)
     yevp = _pad_to(_pad_to(y_ev, 0, BB), 2, BF)
-    newlyp = _pad_to(newly, 0, BB)
+    newlyp = _pad_to(newly.astype(jnp.int32), 0, BB)
     ynewp = _pad_to(_pad_to(y_new, 0, BB), 1, BF)
     t0p = _pad_to(t0[:, None], 0, BB)
     dtp = _pad_to(dt[:, None], 0, BB)
-    firedp = _pad_to(fired, 0, BB)
+    firedp = _pad_to(fired.astype(jnp.int32), 0, BB)
     evtp = _pad_to(ev_t, 0, BB)
     evyp = _pad_to(_pad_to(ev_y, 0, BB), 2, BF)
     bp = xp.shape[0]

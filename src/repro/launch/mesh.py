@@ -10,16 +10,24 @@ FSDP axis; gradients reduce over ("pod", "data")).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    # Auto axes: the sharding rules place arrays with with_sharding_constraint
+    # under an ambient mesh, which explicit-axis meshes (the default of
+    # jax.make_mesh) reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Mesh over whatever devices exist (tests / reduced-config runs)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))

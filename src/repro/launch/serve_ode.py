@@ -18,12 +18,14 @@ apples-to-apples comparison against per-request dispatch lives in
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import SolveRequest, SolveService
+from repro.core import SolveRequest, SolveService, Status
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _decay(t, y, args):
@@ -68,6 +70,7 @@ def main() -> None:
     parser.add_argument("--seed", type=int, default=0)
     opts = parser.parse_args()
 
+    enable_compile_cache()
     svc = SolveService(max_batch=opts.max_batch,
                        max_delay=opts.deadline_ms / 1e3,
                        max_inflight=0 if opts.sync else opts.max_inflight)
@@ -85,15 +88,23 @@ def main() -> None:
     futures = [svc.submit(r) for r in stream]
     svc.flush()
     svc.drain()
-    sols = [f.result() for f in futures]
+    n_ok = n_errors = 0
+    for fut in futures:
+        try:
+            n_ok += bool((fut.result().status == Status.SUCCESS).all())
+        except Exception as e:  # noqa: BLE001 -- counted and reported below
+            n_errors += 1
+            print(f"request failed: {type(e).__name__}: {e}", file=sys.stderr)
     wall = time.perf_counter() - t0
 
-    ok = sum(bool(s.success.all()) for s in sols)
-    print(f"served {len(sols)} requests in {wall:.3f}s "
-          f"({len(sols) / wall:.1f} req/s end-to-end), {ok} fully successful")
+    print(f"served {len(futures)} requests in {wall:.3f}s "
+          f"({len(futures) / wall:.1f} req/s end-to-end), {n_ok} fully "
+          f"successful, {n_errors} raised")
     for name, value in svc.stats().items():
         print(f"  {name:>24}: {value:.4g}" if isinstance(value, float)
               else f"  {name:>24}: {value}")
+    if n_ok != len(futures):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

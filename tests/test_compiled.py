@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro.core import (
     AutoDiffAdjoint,
@@ -428,8 +429,6 @@ class TestShardedSolve:
     XLA_FLAGS=--xla_force_host_platform_device_count=4."""
 
     def _mesh(self):
-        from jax.sharding import Mesh
-
         return Mesh(np.array(jax.devices()), ("data",))
 
     def test_matches_single_device_exactly_mixed_tolerances(self):
@@ -567,3 +566,54 @@ class TestBackendErrors:
         finally:
             ops.set_backend(old)
         assert ops.backend() == old
+
+    def test_misspelt_env_backend_raises(self, monkeypatch):
+        """A typo in REPRO_KERNEL_BACKEND raises on first use instead of
+        latching a backend no op can dispatch to."""
+        from repro.kernels import ops
+
+        old = ops.backend()
+        try:
+            monkeypatch.setenv("REPRO_KERNEL_BACKEND", "palas")
+            ops.reset_backend()
+            with pytest.raises(ValueError, match="unknown kernel backend 'palas'"):
+                ops.backend()
+        finally:
+            ops.set_backend(old)
+
+
+class TestBackendInCacheKeys:
+    """A program is traced for the kernel backend active at trace time, so
+    switching backends in one process must select a different program."""
+
+    def test_compiled_solver_misses_once_per_backend(self):
+        from repro.kernels import ops
+
+        solver = CompiledSolver("dopri5")
+        y0 = jnp.ones((3, 2))
+        old = ops.backend()
+        try:
+            keys = []
+            for name in ("ref", "interpret"):
+                ops.set_backend(name)
+                keys.append(solver.cache_key(decay, y0, t_start=0.0, t_end=0.5))
+                solver.solve(decay, jnp.ones((3, 2)), t_start=0.0, t_end=0.5)
+        finally:
+            ops.set_backend(old)
+        assert keys[0] != keys[1]
+        assert solver.cache_info().misses == 2
+
+    def test_sharded_solve_misses_once_per_backend(self):
+        from repro.core import compiled
+        from repro.kernels import ops
+
+        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+        before = compiled._SHARDED_CACHE.misses
+        old = ops.backend()
+        try:
+            for name in ("ref", "interpret"):
+                ops.set_backend(name)
+                sharded_solve(mesh, decay, jnp.ones((2, 2)), t_start=0.0, t_end=0.5)
+        finally:
+            ops.set_backend(old)
+        assert compiled._SHARDED_CACHE.misses - before == 2

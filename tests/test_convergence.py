@@ -9,15 +9,15 @@ log(error) vs log(dt) is within 0.4 of the tableau's nominal order.
 Implicit tableaus additionally run through the fused factor-once chord-Newton
 path (``fused=True``), which must preserve the discretization order.
 
-Runs in float64 (via the ``jax.experimental.enable_x64`` context, so the
+Runs in float64 (via the ``jax.enable_x64`` context, so the
 global f32 default of the rest of the suite is untouched): order-5 methods
 reach ~1e-11 errors at the small-dt end, far below f32 resolution.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (
     TABLEAUS,
@@ -71,7 +71,7 @@ def measured_order(name: str, fused: bool = False) -> tuple[float, np.ndarray]:
 
 @pytest.mark.parametrize("name", sorted(TABLEAUS))
 def test_empirical_order_matches_nominal(name):
-    with enable_x64():
+    with jax.enable_x64(True):
         order, err = measured_order(name)
     nominal = TABLEAUS[name].order
     assert abs(order - nominal) <= 0.4, (
@@ -82,7 +82,7 @@ def test_empirical_order_matches_nominal(name):
 @pytest.mark.parametrize("name", sorted(TABLEAUS))
 def test_errors_decrease_monotonically(name):
     """Halving dt must never increase the error anywhere in the sweep."""
-    with enable_x64():
+    with jax.enable_x64(True):
         _, err = measured_order(name)
     assert np.all(np.diff(err) < 0), f"{name}: errors not monotone: {err}"
 
@@ -91,7 +91,7 @@ def test_errors_decrease_monotonically(name):
 def test_fused_implicit_order_matches_nominal(name):
     """The factor-once fused DIRK path preserves the discretization order on
     every implicit tableau (and engages on every step)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         order, err = measured_order(name, fused=True)
     nominal = TABLEAUS[name].order
     assert abs(order - nominal) <= 0.4, (

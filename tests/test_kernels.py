@@ -81,7 +81,8 @@ class TestErrorNorm:
 
 
 class TestInterp:
-    @pytest.mark.parametrize("b,n,f", [(1, 1, 1), (3, 7, 5), (8, 128, 128), (5, 200, 2)])
+    @pytest.mark.parametrize("b,n,f", [(1, 1, 1), (3, 7, 5), (8, 128, 128), (5, 200, 2),
+                                       (4, 9, 31), (4, 9, 32)])
     def test_matches_ref(self, b, n, f):
         rng = np.random.default_rng(b * n + f)
         coeffs = tuple(jnp.asarray(rng.standard_normal((b, f)), jnp.float32) for _ in range(4))
@@ -90,6 +91,21 @@ class TestInterp:
         out = jnp.asarray(rng.standard_normal((b, n, f)), jnp.float32)
         r = ref.interp_eval(coeffs, x, mask, out)
         p = pi.interp_eval(coeffs, x, mask, out, interpret=True)
+        np.testing.assert_allclose(r, p, rtol=3e-5, atol=3e-5)
+
+    @pytest.mark.parametrize("b,n,f", [(9, 200, 2), (3, 8, 130)])
+    def test_all_false_rows_keep_the_buffer(self, b, n, f):
+        """Rows whose mask is all-false pass the buffer through untouched,
+        bitwise, whatever the flattened (point, feature) tiling."""
+        rng = np.random.default_rng(b + n + f)
+        coeffs = tuple(jnp.asarray(rng.standard_normal((b, f)), jnp.float32) for _ in range(4))
+        x = jnp.asarray(rng.uniform(0, 1, (b, n)), jnp.float32)
+        mask = rng.uniform(size=(b, n)) > 0.5
+        mask[::2] = False
+        out = jnp.asarray(rng.standard_normal((b, n, f)), jnp.float32)
+        p = np.asarray(pi.interp_eval(coeffs, x, jnp.asarray(mask), out, interpret=True))
+        np.testing.assert_array_equal(p[::2], np.asarray(out)[::2])
+        r = ref.interp_eval(coeffs, x, jnp.asarray(mask), out)
         np.testing.assert_allclose(r, p, rtol=3e-5, atol=3e-5)
 
     def test_horner_is_a_polynomial(self):
@@ -230,6 +246,44 @@ class TestFusedEventOps:
         ):
             np.testing.assert_array_equal(np.asarray(rr), np.asarray(pp),
                                           err_msg=name)
+
+
+    def test_three_events_mixed_directions(self):
+        """E=3 with one rising, one falling and one two-sided event, every
+        sign pattern present: detect and commit match the ref op bitwise."""
+        b, E, f = 19, 3, 5
+        rng, _, _, fired, accept = self._detect_inputs(99, b, E)
+        signs = np.array([-1.0, 0.0, 1.0], np.float32)
+        v_prev = jnp.asarray(np.stack([np.roll(signs, i) for i in range(b)])[:, :E]
+                             * rng.uniform(0.5, 2.0, (b, E)), jnp.float32)
+        v_new = jnp.asarray(np.stack([np.roll(signs, i // 3) for i in range(b)])[:, :E]
+                            * rng.uniform(0.5, 2.0, (b, E)), jnp.float32)
+        directions = (1.0, -1.0, 0.0)
+        r = ref.fused_event_detect(v_prev, v_new, fired, accept, directions=directions)
+        p = pi.fused_event_detect(v_prev, v_new, fired, accept, directions=directions,
+                                  interpret=True)
+        np.testing.assert_array_equal(np.asarray(r[0]), np.asarray(p[0]))
+        np.testing.assert_array_equal(np.asarray(r[1]), np.asarray(p[1]))
+        newly = r[0]
+        assert np.asarray(newly).any(axis=0).all()  # every event fires somewhere
+        args = (
+            jnp.asarray(rng.uniform(0.0, 1.0, (b, E)), jnp.float32),
+            jnp.asarray(rng.standard_normal((b, E, f)), jnp.float32),
+            newly,
+            jnp.asarray(rng.standard_normal((b, f)), jnp.float32),
+            jnp.asarray(rng.uniform(0.0, 1.0, b), jnp.float32),
+            jnp.asarray(rng.uniform(0.05, 0.2, b), jnp.float32),
+            fired,
+            jnp.full((b, E), jnp.nan, jnp.float32),
+            jnp.zeros((b, E, f), jnp.float32),
+        )
+        terminal = (True, False, True)
+        r = ref.fused_event_commit(*args, terminal=terminal)
+        p = pi.fused_event_commit(*args, terminal=terminal, interpret=True)
+        for name, rr, pp in zip(
+            ("fired", "ev_t", "ev_y", "stop", "t_stop", "y_stop", "n_new"), r, p
+        ):
+            np.testing.assert_array_equal(np.asarray(rr), np.asarray(pp), err_msg=name)
 
 
 class TestBackendDispatch:

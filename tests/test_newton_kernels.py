@@ -183,3 +183,30 @@ class TestFusedNewtonIter:
         assert not np.array_equal(np.asarray(k_new)[0], np.asarray(k)[0])
 
 
+class TestLargeSystemsWithRowSwaps:
+    """n=128 chord matrices whose largest entry in every column sits off the
+    diagonal, so every elimination step swaps rows: factor and iteration
+    match the ref ops and solve the system."""
+
+    def test_factor_and_newton_iter_at_n128(self):
+        rng = np.random.default_rng(128)
+        b, f = 3, 128
+        # A cyclic shift makes row (j + 1) the dominant one in column j.
+        shift = np.roll(np.eye(f), 1, axis=0)
+        A = jnp.asarray(4.0 * shift + 0.1 * rng.standard_normal((b, f, f)), jnp.float32)
+        r_lu, r_p = ref.batched_lu_factor(A)
+        p_lu, p_p = pi.batched_lu_factor(A, interpret=True)
+        assert (np.asarray(p_p) != np.arange(f)).any(axis=1).all()
+        np.testing.assert_array_equal(np.asarray(r_p), np.asarray(p_p))
+        np.testing.assert_allclose(r_lu, p_lu, rtol=1e-4, atol=1e-4)
+        k, fk = [jnp.asarray(rng.standard_normal((b, f)), jnp.float32) for _ in range(2)]
+        active = jnp.asarray([True, False, True])
+        scale = jnp.ones((b, f), jnp.float32)
+        rk, rn = ref.fused_newton_iter(r_lu, r_p, k, fk, active, scale)
+        pk, pn = pi.fused_newton_iter(p_lu, p_p, k, fk, active, scale, interpret=True)
+        np.testing.assert_allclose(rk, pk, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(rn, pn, rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(pk)[1], np.asarray(k)[1])
+        delta = np.asarray(k) - np.asarray(pk)
+        res = np.einsum("bij,bj->bi", np.asarray(A), delta) - np.asarray(k - fk)
+        np.testing.assert_allclose(res[[0, 2]], 0.0, atol=1e-4)
