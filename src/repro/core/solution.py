@@ -56,7 +56,16 @@ class Solution:
             accumulators contributed by each component (stepper: n_f_evals,
             controller: n_accepted, step function: n_steps, n_initialized,
             and n_events when events are registered, plus any user-registered
-            contributors)
+            contributors).  Implicit steppers add n_newton_iters (the
+            instance's own Newton iterations), n_jac_evals, n_newton_trips
+            and n_newton_rejects.  n_newton_trips counts the batched Newton
+            iterations of every attempt the instance ran, i.e. the
+            linear-solve launches its lane paid for while it had converged
+            or still iterated; like n_f_evals it depends on the batch's
+            composition, and its max over the batch is the solve's number of
+            launches.  n_newton_rejects counts the attempts rejected because
+            the instance's own Newton solve failed (diverged or hit
+            max_iters), whatever the error test said
 
     When events are registered (all None otherwise; E = number of events):
 

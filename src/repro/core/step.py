@@ -33,9 +33,15 @@ an ``init_stats(batch) -> dict`` hook and advances them in
 ``update_stats(stats, ctx) -> dict``, where ``ctx`` is a ``StepContext``
 describing the step just taken.  The stepper records ``n_f_evals``, the
 controller ``n_accepted``, the step function itself ``n_steps`` and
-``n_initialized``; user code can register additional contributors through
-``extra_stats`` to record any solver-internal metric (paper Sec. 3's
-per-instance stats, generalized).
+``n_initialized``.  Implicit steppers add the Newton layer's counts from
+their ``StepContext.aux``: ``n_newton_iters`` and ``n_jac_evals`` per
+instance, ``n_newton_trips`` (the batched Newton iterations, one
+linear-solve launch each, of every attempt the instance ran -- the launches
+its lane paid for, so it depends on the batch's composition like
+``n_f_evals``) and ``n_newton_rejects`` (attempts rejected because the
+instance's own Newton solve failed).  User code can register additional
+contributors through ``extra_stats`` to record any solver-internal metric
+(paper Sec. 3's per-instance stats, generalized).
 """
 
 from __future__ import annotations

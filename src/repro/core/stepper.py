@@ -350,8 +350,12 @@ class DiagonallyImplicitRK(AbstractStepper):
     ``DeprecationWarning`` and cannot be combined with ``newton=``.
 
     Statistics: ``n_f_evals`` (batched Newton evaluations, overhanging),
-    ``n_newton_iters`` (per-instance inner iterations while running) and
-    ``n_jac_evals`` (per-instance Jacobian evaluations).
+    ``n_newton_iters`` (per-instance inner iterations while running),
+    ``n_jac_evals`` (per-instance Jacobian evaluations), ``n_newton_trips``
+    (the batched Newton iterations of every attempt the instance ran: the
+    linear-solve launches its lane paid for, converged or not, so it depends
+    on the batch's composition) and ``n_newton_rejects`` (attempts rejected
+    because the instance's own Newton solve failed).
     """
 
     def __init__(
@@ -492,7 +496,13 @@ class DiagonallyImplicitRK(AbstractStepper):
                 n_newton_iters = n_newton_iters + res.n_iters
                 n_evals = n_evals + res.n_evals
 
-        stats_aux = {"n_newton_iters": n_newton_iters, "n_jac_evals": n_jac_evals}
+        stats_aux = {
+            "n_newton_iters": n_newton_iters,
+            "n_jac_evals": n_jac_evals,
+            # One batched linear solve per Newton trip, whoever still iterates.
+            "n_newton_trips": n_evals,
+            "n_newton_rejects": failed.astype(jnp.int32),
+        }
         carry_out = DIRKCarry(jac=J, refresh=failed | slow)
         return jnp.stack(ks), carry_out, failed, n_static_evals, n_evals, stats_aux
 
@@ -562,7 +572,13 @@ class DiagonallyImplicitRK(AbstractStepper):
     # --- statistics registry contribution ---
     def init_stats(self, batch: int) -> dict[str, jax.Array]:
         zeros = jnp.zeros((batch,), dtype=jnp.int32)
-        return {"n_f_evals": zeros, "n_newton_iters": zeros, "n_jac_evals": zeros}
+        return {
+            "n_f_evals": zeros,
+            "n_newton_iters": zeros,
+            "n_jac_evals": zeros,
+            "n_newton_trips": zeros,
+            "n_newton_rejects": zeros,
+        }
 
     def update_stats(self, stats: dict, ctx) -> dict:
         aux = ctx.aux or {}
@@ -571,12 +587,7 @@ class DiagonallyImplicitRK(AbstractStepper):
             **stats,
             "n_f_evals": stats["n_f_evals"] + ctx.step_active * ctx.n_f_evals,
         }
-        if "n_newton_iters" in aux:
-            out["n_newton_iters"] = (
-                stats["n_newton_iters"] + ctx.step_active * running * aux["n_newton_iters"]
-            )
-        if "n_jac_evals" in aux:
-            out["n_jac_evals"] = (
-                stats["n_jac_evals"] + ctx.step_active * running * aux["n_jac_evals"]
-            )
+        for name in ("n_newton_iters", "n_jac_evals", "n_newton_trips", "n_newton_rejects"):
+            if name in aux:
+                out[name] = stats[name] + ctx.step_active * running * aux[name]
         return out

@@ -59,7 +59,7 @@ def _assert_bitwise(a, c):
     np.testing.assert_array_equal(np.asarray(a.ts), np.asarray(c.ts))
     np.testing.assert_array_equal(np.asarray(a.status), np.asarray(c.status))
     for key in ("n_steps", "n_accepted", "n_f_evals", "n_newton_iters",
-                "n_jac_evals"):
+                "n_jac_evals", "n_newton_trips", "n_newton_rejects"):
         np.testing.assert_array_equal(
             np.asarray(a.stats[key]), np.asarray(c.stats[key]), err_msg=key)
     np.testing.assert_array_equal(np.asarray(c.stats["n_fused_steps"]),
