@@ -14,12 +14,12 @@ iterations and Jacobian evaluations.  The explicit method gets a generous but
 bounded step budget; when it hits the cap the step ratio reported is a lower
 bound.
 
-A third section benchmarks the FUSED diagonally-implicit step (factor-once
-chord Newton, one launch per iteration) against the unfused op-per-op path on
+A third section times the diagonally-implicit step, fused and unfused, on
 the ``interpret`` kernel backend -- the launch-count proxy tier, same as
-``step_bench`` -- on a stiff Allen-Cahn method-of-lines problem where the
-per-iteration O(n^3) elimination the fused path removes actually dominates.
-``--bars`` enforces the committed speedup floors.
+``step_bench`` -- on a stiff Allen-Cahn method-of-lines problem.  Both paths
+factor the chord matrix once per step attempt and substitute per Newton
+iteration; ``fused=True`` differs only by the ``fused_step`` megakernel tail,
+so the speedup row is informational and has no floor.
 
 ``REPRO_STIFF_SMOKE=1`` shrinks batch/horizons/budgets for CI smoke runs.
 """
@@ -55,10 +55,10 @@ def allen_cahn(t, y, args):
     return lam * (up - 2.0 * y + dn) + y - y**3
 
 
-# (method, batch, n_feat, t_end, speedup bar) for the fused-DIRK section.
+# (method, batch, n_feat, t_end) for the fused-DIRK section.
 # One point keeps the interpret-tier suite affordable; n_feat is large enough
 # that the factored system, not launch bookkeeping, is the per-iteration cost.
-FUSED_POINTS = (("kvaerno5", 4, 32, 1.0, 2.0),)
+FUSED_POINTS = (("kvaerno5", 4, 32, 1.0),)
 
 
 def _fused_dirk_rows(repeats=2):
@@ -73,7 +73,7 @@ def _fused_dirk_rows(repeats=2):
     out = []
     try:
         ops.set_backend("interpret")
-        for method, batch, n_feat, t_end, bar in FUSED_POINTS:
+        for method, batch, n_feat, t_end in FUSED_POINTS:
             if not smoke:
                 t_end *= 5.0
             lam = float((n_feat + 1) ** 2)
@@ -103,7 +103,7 @@ def _fused_dirk_rows(repeats=2):
                             per_sec[label], f"{n_loop} loop steps, b={batch} f={n_feat}"))
             out.append((f"fused_dirk/{method}/fused_speedup",
                         per_sec["fused"] / per_sec["unfused"],
-                        f"steps/sec ratio, fused over unfused (bar {bar}x)"))
+                        "steps/sec ratio, fused over unfused"))
     finally:
         ops.set_backend(prev)
     return out
@@ -175,20 +175,13 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--json", nargs="?", const="BENCH_stiff.json", default=None,
                         metavar="PATH")
-    parser.add_argument("--bars", action="store_true",
-                        help="fail if any fused_speedup row misses its floor "
-                             "(use when refreshing the committed baseline)")
     opts = parser.parse_args()
 
-    bars = {f"fused_dirk/{p[0]}/fused_speedup": p[4] for p in FUSED_POINTS}
     records = []
-    missed = []
     print("name,value,derived")
     for name, v, extra in rows():
         print(f"stiff/{name},{v},{extra}", flush=True)
         records.append({"suite": "stiff", "name": name, "value": v, "derived": extra})
-        if opts.bars and name in bars and v < bars[name]:
-            missed.append(f"{name}: {v:.3f}x < bar {bars[name]}x")
 
     if opts.json:
         from .common import calibration_us
@@ -198,9 +191,6 @@ def main() -> None:
         with open(opts.json, "w") as fh:
             json.dump(payload, fh, indent=2)
         print(f"# wrote {len(records)} rows to {opts.json}")
-
-    if missed:
-        raise SystemExit("speedup below bar:\n  " + "\n  ".join(missed))
 
 
 if __name__ == "__main__":

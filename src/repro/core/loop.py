@@ -120,8 +120,8 @@ def solve_ivp(
             op per step attempt around the vf calls, zero vf launches for
             ``polynomial_term`` dynamics).  Engages for every explicit
             tableau (FSAL or not, adaptive or fixed-step) and for
-            ``DiagonallyImplicitRK`` (factor-once chord Newton: one LU
-            factorization per step, one fused launch per Newton iteration)
+            ``DiagonallyImplicitRK`` (whose factor-once Newton sweep is the
+            same either way: ``fused`` only swaps in the megakernel tail)
             under PID-family or fixed controllers, falling back transparently
             otherwise; ``stats["n_fused_steps"]`` reports whether it ran and
             ``stats["fused_fallback_reason"]`` (a ``FusedFallbackReason``

@@ -14,21 +14,23 @@ once per solver step from a (possibly stale, per-instance refreshed) Jacobian
 and reused across all stages and iterations.  Two linear-algebra strategies
 share the loop:
 
-``M`` path
-    Each iteration runs a full batched dense solve against ``M``
-    (``ops.batched_linsolve``) followed by the masked commit + convergence
-    norm (``ops.masked_newton_update``).  This is the external-caller
-    fallback: no precomputation required.
-
 ``operator`` path (factor once)
     The caller factors ``M`` once per step via ``ops.batched_lu_factor``
     (partial-pivoted LU) and every iteration runs ONE fused op,
     ``ops.fused_newton_iter``: residual, permutation scatter, the two
     triangular back-substitutions against the prefactored LU, masked commit,
-    and the scaled-RMS norm in a single launch.  On the ref backend the LU
-    composition reproduces ``jnp.linalg.solve`` bitwise (it is the same
-    ``lax.linalg.lu`` + triangular-solve sequence ``solve`` lowers to), so
-    both paths yield identical iterates.
+    and the scaled-RMS norm in a single launch.  ``DiagonallyImplicitRK``
+    takes this path on every step, fused or not.
+
+``M`` path
+    Each iteration runs a full batched dense solve against ``M``
+    (``ops.batched_linsolve``) followed by the masked commit + convergence
+    norm (``ops.masked_newton_update``).  This is the external-caller API:
+    no precomputation required, but an O(n^3) elimination per iteration.
+
+On the ref backend the LU composition reproduces ``jnp.linalg.solve``
+bitwise (it is the same ``lax.linalg.lu`` + triangular-solve sequence
+``solve`` lowers to), so both paths yield identical iterates.
 """
 
 from __future__ import annotations
@@ -125,8 +127,10 @@ def newton_solve(
     - ``M``: the chord matrix itself; each iteration runs a fresh batched
       dense solve (``ops.batched_linsolve``).
     - ``operator``: the ``(lu, permutation)`` pair from
-      ``ops.batched_lu_factor(M)``; each iteration runs the single fused
-      ``ops.fused_newton_iter`` launch against the prefactored LU.
+      ``ops.batched_lu_factor(M)``, in the kernel backend's own layout
+      (build it under the backend that runs the solve); each iteration
+      runs the single fused ``ops.fused_newton_iter`` launch against the
+      prefactored LU.
 
     All numeric knobs live on ``config`` (a :class:`NewtonConfig`); ``None``
     means the defaults.

@@ -338,10 +338,13 @@ class DiagonallyImplicitRK(AbstractStepper):
 
     Every implicit stage shares the tableau's single diagonal coefficient
     ``gamma``, so one chord matrix ``M = I - dt*gamma*J`` (per instance)
-    serves all stages of a step.  ``J`` comes from ``ODETerm.vf_jac`` and is
-    reused across stages *and* steps; an instance re-evaluates it only when
-    its ``refresh`` flag is set (Newton failed or converged slowly), so
-    well-behaved instances amortize one Jacobian over many steps.
+    serves all stages of a step: it is LU-factored once per step attempt and
+    every Newton iteration of every stage back-substitutes against the
+    factors, with ``fused=`` or without.  ``J`` comes from
+    ``ODETerm.vf_jac`` and is reused across stages *and* steps; an instance
+    re-evaluates it only when its ``refresh`` flag is set (Newton failed or
+    converged slowly), so well-behaved instances amortize one Jacobian over
+    many steps.
 
     All inner-solver knobs live on ONE object: pass
     ``newton=NewtonConfig(tol=..., max_iters=..., divergence_rate=...,
@@ -420,17 +423,16 @@ class DiagonallyImplicitRK(AbstractStepper):
             refresh=jnp.ones((b,), dtype=bool),
         )
 
-    def _stage_sweep(self, term, t, dt, y, f0, args, carry, scale, *, factor_once):
+    def _stage_sweep(self, term, t, dt, y, f0, args, carry, scale):
         """The shared stage recursion of the unfused and fused DIRK paths:
-        per-instance Jacobian refresh, chord-matrix build, and one masked
-        Newton solve per implicit stage.  ``factor_once=False`` is the
-        classic path (each iteration re-solves against ``M`` through
-        ``batched_linsolve``); ``factor_once=True`` factors ``M`` ONCE via
-        ``ops.batched_lu_factor`` and runs every Newton iteration as one
-        ``ops.fused_newton_iter`` launch against the prefactored LU.  On the
-        ref backend the two produce bitwise-identical iterates (the LU
-        composition is exactly what ``jnp.linalg.solve`` lowers to), so the
-        fused and unfused DIRK solves stay bitwise-equal there.
+        per-instance Jacobian refresh, chord-matrix build, ONE
+        ``ops.batched_lu_factor`` of ``M`` per step attempt, and one masked
+        Newton solve per implicit stage whose every iteration is a single
+        ``ops.fused_newton_iter`` launch against the prefactored LU -- the
+        O(n^3) elimination is paid once per attempt, each iteration costs
+        O(n^2) substitutions.  On the ref backend the LU composition is
+        exactly what ``jnp.linalg.solve`` lowers to, so the iterates are
+        bitwise those of a dense solve against ``M`` in every iteration.
 
         Returns ``(K, carry_out, failed, n_static_evals, n_evals, stats_aux)``.
         """
@@ -452,7 +454,7 @@ class DiagonallyImplicitRK(AbstractStepper):
         n_jac_evals = carry.refresh.astype(jnp.int32)
         eye = jnp.eye(y.shape[1], dtype=dtype)
         M = eye - (dt * self.gamma)[:, None, None] * J
-        operator = ops.batched_lu_factor(M) if factor_once else None
+        operator = ops.batched_lu_factor(M)
 
         ks: list[jax.Array] = []
         failed = jnp.zeros(dt.shape, dtype=bool)
@@ -481,15 +483,10 @@ class DiagonallyImplicitRK(AbstractStepper):
                 # so the test matches the atol/rtol error scale.
                 stage_scale = scale / jnp.maximum(jnp.abs(dtg), jnp.finfo(dtype).tiny)
                 pred = ks[-1] if ks else f0  # predictor: the previous stage slope
-                if factor_once:
-                    res = newton_solve(
-                        eval_fn, pred, scale=stage_scale,
-                        operator=operator, config=self.newton,
-                    )
-                else:
-                    res = newton_solve(
-                        eval_fn, pred, M, stage_scale, config=self.newton,
-                    )
+                res = newton_solve(
+                    eval_fn, pred, scale=stage_scale,
+                    operator=operator, config=self.newton,
+                )
                 ks.append(res.k)
                 failed = failed | ~res.converged
                 slow = slow | (res.n_iters >= slow_iters)
@@ -510,7 +507,7 @@ class DiagonallyImplicitRK(AbstractStepper):
         tab = self.tableau
         _, _, b_sol, b_err = _tableau_arrays(tab, y.dtype)
         K, carry_out, failed, n_static_evals, n_evals, stats_aux = self._stage_sweep(
-            term, t, dt, y, f0, args, carry, scale, factor_once=False
+            term, t, dt, y, f0, args, carry, scale
         )
         y1, err = ops.fused_update(y, K, dt, b_sol, b_err)
         if tab.stiffly_accurate and tab.c[-1] == 1.0:
@@ -530,8 +527,8 @@ class DiagonallyImplicitRK(AbstractStepper):
         )
 
     def fused_stage_parts(self, term, t, dt, y, f0, args, carry, scale):
-        """The DIRK half of the fused fast path: the stage sweep with the
-        factor-once Newton strategy (one ``batched_lu_factor`` per step, one
+        """The DIRK half of the fused fast path: the stage sweep ``step``
+        runs too (one ``batched_lu_factor`` per step, one
         ``fused_newton_iter`` launch per Newton iteration), plus the trailing
         derivative -- everything the ``fused_step`` megakernel needs as
         inputs.  The combine/norm/controller/commit happen in-kernel, with
@@ -543,7 +540,7 @@ class DiagonallyImplicitRK(AbstractStepper):
         tab = self.tableau
         _, _, b_sol, b_err = _tableau_arrays(tab, y.dtype)
         K, carry_out, failed, n_static_evals, n_evals, stats_aux = self._stage_sweep(
-            term, t, dt, y, f0, args, carry, scale, factor_once=True
+            term, t, dt, y, f0, args, carry, scale
         )
         if tab.stiffly_accurate and tab.c[-1] == 1.0:
             f1 = K[-1]

@@ -515,17 +515,24 @@ def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
 
 
 def batched_lu_factor(A, *, interpret=False):
+    """Returns the operator in ``fused_newton_iter``'s layout, built once per
+    factorisation so that no Newton iteration writes anything of size n^2:
+    the packed factors TRANSPOSED, ``lut[i, c, r] == lu[i, r, c]``, as
+    ``(bp, fr, fr)`` with the batch and both factor axes padded to the
+    8-sublane tile (identity on the padded block and on padded instances),
+    and the ``(bp, fr)`` int32 permutation (identity on padded entries).
+    ``unpack_lu`` reads it back in ``lax.linalg.lu``'s layout."""
     b, f = A.shape[0], A.shape[1]
     # Same padding plan as ``batched_linsolve``: rows to the 8-sublane
     # layout, columns to the lane dimension, identity on the padded diagonal
     # so the padded block never pivots into the real rows.
     Ap = _pad_to(_pad_to(_pad_to(A, 0, BB), 1, BB), 2, BF)
     bp_, fr, fc = Ap.shape
-    pad_eye = (
-        (jnp.arange(fr)[:, None] == jnp.arange(fc)[None, :])
-        & (jnp.arange(fr)[:, None] >= f)
-    ).astype(A.dtype)
-    Ap = Ap + pad_eye[None, :, :]
+    rr = jnp.arange(fr)[:, None]
+    pad_eye = (rr == jnp.arange(fc)[None, :]) & (
+        (rr >= f) | (jnp.arange(bp_)[:, None, None] >= b)
+    )
+    Ap = Ap + pad_eye.astype(A.dtype)
     lu, perm = pl.pallas_call(
         functools.partial(_lu_factor_kernel, n=f),
         grid=(bp_ // BB,),
@@ -540,7 +547,14 @@ def batched_lu_factor(A, *, interpret=False):
         ],
         interpret=interpret,
     )(Ap)
-    return lu[:b, :f, :f], perm[:b, :f]
+    # The substitution kernel reads factor columns as sublane rows.
+    return jnp.swapaxes(lu[:, :, :fr], 1, 2), perm
+
+
+def unpack_lu(lut, perm, b, f):
+    """``batched_lu_factor``'s operator as ``lax.linalg.lu`` lays it out:
+    the ``(b, f, f)`` packed factors and the ``(b, f)`` permutation."""
+    return jnp.swapaxes(lut, 1, 2)[:b, :f, :f], perm[:b, :f]
 
 
 # ----------------------------------------------------------- fused newton iter
@@ -599,24 +613,13 @@ def _newton_iter_kernel(
     res_out[...] = jnp.sqrt(jnp.sum(r * r, axis=1, keepdims=True) / n_feat)
 
 
-def fused_newton_iter(lu, perm, k, fk, active, scale, *, interpret=False):
+def fused_newton_iter(lut, perm, k, fk, active, scale, *, interpret=False):
+    """``lut, perm`` is ``batched_lu_factor``'s operator, used as laid out:
+    the padded factor block is identity and the padded permutation entries
+    map to themselves, so padded residual entries stay 0."""
     b, f = k.shape
+    bp_, fr, _ = lut.shape
     scale = jnp.broadcast_to(jnp.asarray(scale, k.dtype), (b, f))
-    # Both factor axes pad to the 8-sublane layout: the kernel reads factor
-    # columns as sublane rows of the transpose.
-    lup = _pad_to(_pad_to(_pad_to(lu, 0, BB), 1, BB), 2, BB)
-    bp_, fr, _ = lup.shape
-    # Re-seat the padded diagonal (the wrapper contract is the sliced true
-    # factors) so the backward substitution never divides by a padded zero
-    # on real batch rows; padded residual entries are 0 either way.
-    pad_eye = (
-        (jnp.arange(fr)[:, None] == jnp.arange(fr)[None, :])
-        & (jnp.arange(fr)[:, None] >= f)
-    ).astype(lu.dtype)
-    lut = jnp.swapaxes(lup + pad_eye[None, :, :], 1, 2)
-    ids = jnp.arange(fr, dtype=perm.dtype)
-    permp = _pad_to(_pad_to(perm, 0, BB), 1, BB)
-    permp = jnp.where(ids[None, :] >= f, ids[None, :], permp)
     # Padded deltas are 0 and padded scales 1 -> padded cells add 0 to the
     # sum of squares; divide by the TRUE feature count.
     kp = _pad_to(_pad_to(k, 0, BB), 1, BB)
@@ -643,7 +646,7 @@ def fused_newton_iter(lu, perm, k, fk, active, scale, *, interpret=False):
             jax.ShapeDtypeStruct((bp_, 1), k.dtype),
         ],
         interpret=interpret,
-    )(lut, permp, kp, fkp, ap, sp)
+    )(lut, perm, kp, fkp, ap, sp)
     return k_new[:b, :f], res[:b, 0]
 
 
@@ -1339,8 +1342,8 @@ class _Impl:
     def batched_lu_factor(self, A):
         return batched_lu_factor(A, interpret=self._i)
 
-    def fused_newton_iter(self, lu, perm, k, fk, active, scale):
-        return fused_newton_iter(lu, perm, k, fk, active, scale, interpret=self._i)
+    def fused_newton_iter(self, lut, perm, k, fk, active, scale):
+        return fused_newton_iter(lut, perm, k, fk, active, scale, interpret=self._i)
 
     def masked_newton_update(self, k, delta, active, scale):
         return masked_newton_update(k, delta, active, scale, interpret=self._i)

@@ -113,7 +113,10 @@ def batched_lu_factor(A):
     triangle in one (b, f, f) array) and the (b, f) int32 row permutation.
     This is the factor-once half of the fused Newton path -- every subsequent
     ``fused_newton_iter`` launch back-substitutes against these factors
-    instead of re-eliminating the same matrix.
+    instead of re-eliminating the same matrix.  The pair is an operator for
+    the same backend's ``fused_newton_iter``: the Pallas backend returns it
+    already in its substitution kernel's padded, transposed layout
+    (``pallas_impl.unpack_lu`` reads that back as this one).
 
     ``lax.linalg.lu`` is the exact factorization ``jnp.linalg.solve`` (and
     hence ``batched_linsolve``) performs internally, so the factor +

@@ -7,9 +7,12 @@ implicit tableau -- including steps that reject on Newton failure and refresh
 the chord Jacobian -- because ``batched_lu_factor`` + ``fused_newton_iter``
 compose the very jnp primitives ``jnp.linalg.solve`` lowers to, in the same
 order.  On top of that: engagement accounting, the FixedController
-failure-is-not-success path, and ref/interpret parity for the two new ops.
+failure-is-not-success path, ref/interpret parity for the two new ops, and
+the unfused step running the same factor-once sweep as the fused one
+(bitwise on the interpret backend too).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,9 +22,11 @@ from repro.core import (
     DiagonallyImplicitRK,
     FixedController,
     NewtonConfig,
+    ODETerm,
     Status,
     solve_ivp,
 )
+from repro.core.stepper import _tableau_arrays
 from repro.core.tableau import TABLEAUS
 from repro.kernels import ops, pallas_impl as pi, ref
 
@@ -43,6 +48,12 @@ def robertson(t, y, args):
         ),
         axis=-1,
     )
+
+
+def allen_cahn(t, y, lam):
+    up = jnp.concatenate([y[..., 1:], jnp.zeros_like(y[..., :1])], axis=-1)
+    dn = jnp.concatenate([jnp.zeros_like(y[..., :1]), y[..., :-1]], axis=-1)
+    return lam * (up - 2.0 * y + dn) + y - y**3
 
 
 @pytest.fixture
@@ -146,6 +157,64 @@ class TestFusedImplicitBitwise:
         _assert_bitwise(a, c)
 
 
+class TestUnfusedStepFactorsOnce:
+    """The unfused DIRK step runs the same factor-once stage sweep as the
+    fused path, so on the interpret backend -- where a Gauss-Jordan solve
+    per iteration and LU substitution round differently -- the two agree
+    bitwise: same K, same carry, same counters."""
+
+    @pytest.mark.parametrize("method", IMPLICIT)
+    def test_step_matches_fused_stage_parts_interpret(self, method):
+        # A 16-point Allen-Cahn semidiscretisation: large enough a chord
+        # matrix that the two linear solvers would round apart.
+        stepper = DiagonallyImplicitRK(method)
+        term = ODETerm(allen_cahn)
+        n = 16
+        mu = jnp.float32((n + 1) ** 2)
+        x = jnp.linspace(0.0, 1.0, n + 2, dtype=jnp.float32)[1:-1]
+        amp = jnp.asarray([0.5, 1.0, 1.5, 2.0], jnp.float32)
+        y = amp[:, None] * jnp.sin(jnp.pi * x)[None, :] + 0.1 * x[None, :]
+        t = jnp.zeros((4,), jnp.float32)
+        dt = jnp.asarray([0.05, 0.02, 0.01, 0.005], jnp.float32)
+        scale = 1e-7 + 1e-4 * jnp.abs(y)
+        _, _, b_sol, b_err = _tableau_arrays(stepper.tableau, y.dtype)
+
+        def unfused(y, f0, carry):
+            return stepper.step(term, t, dt, y, f0, mu, carry, scale)
+
+        def fused(y, f0, carry):
+            return stepper.fused_stage_parts(term, t, dt, y, f0, mu, carry, scale)
+
+        def combine(y, K):
+            return ops.fused_update(y, K, dt, b_sol, b_err)
+
+        old = ops.backend()
+        try:
+            ops.set_backend("interpret")
+            f0 = term.vf(t, y, mu)
+            carry = stepper.init_carry(term, t, y, f0, mu)
+            # Two steps: a fresh Jacobian, then the first step's carry.
+            for _ in range(2):
+                a = jax.jit(unfused)(y, f0, carry)
+                K, f1, n_evals, carry_c, failed, aux = jax.jit(fused)(y, f0, carry)
+                y1, err = jax.jit(combine)(y, K)
+                for name, u, c in (("y1", a.y1, y1), ("err", a.err, err),
+                                   ("f1", a.f1, f1), ("n_f_evals", a.n_f_evals, n_evals),
+                                   ("failed", a.solver_failed, failed),
+                                   ("jac", a.carry.jac, carry_c.jac),
+                                   ("refresh", a.carry.refresh, carry_c.refresh)):
+                    np.testing.assert_array_equal(np.asarray(u), np.asarray(c),
+                                                  err_msg=name)
+                assert set(a.stats_aux) == set(aux)
+                for key in aux:
+                    np.testing.assert_array_equal(np.asarray(a.stats_aux[key]),
+                                                  np.asarray(aux[key]), err_msg=key)
+                assert int(a.n_f_evals) > 0
+                y, f0, carry = a.y1, a.f1, a.carry
+        finally:
+            ops.set_backend(old)
+
+
 class TestNewOpsParity:
     """ref vs pallas-interpret agreement for the two new registry ops."""
 
@@ -169,7 +238,8 @@ class TestNewOpsParity:
 
         lu_r, p_r = ref.batched_lu_factor(A)
         lu_i, p_i = pi.batched_lu_factor(A, interpret=True)
-        np.testing.assert_array_equal(np.asarray(p_r), np.asarray(p_i))
+        np.testing.assert_array_equal(np.asarray(p_r),
+                                      np.asarray(pi.unpack_lu(lu_i, p_i, b, f)[1]))
         k_r, n_r = ref.fused_newton_iter(lu_r, p_r, k, fk, active, scale)
         k_i, n_i = pi.fused_newton_iter(lu_i, p_i, k, fk, active, scale,
                                         interpret=True)

@@ -115,7 +115,7 @@ class TestBatchedLuFactor:
         rng = np.random.default_rng(5 * b + f)
         A = _chord(rng, b, f)
         r_lu, r_p = ref.batched_lu_factor(A)
-        p_lu, p_p = pi.batched_lu_factor(A, interpret=True)
+        p_lu, p_p = pi.unpack_lu(*pi.batched_lu_factor(A, interpret=True), b, f)
         # identical pivot choices (same max-magnitude, first-match rule) ...
         np.testing.assert_array_equal(np.asarray(r_p), np.asarray(p_p))
         # ... and matching factors up to f32 elimination rounding
@@ -126,7 +126,7 @@ class TestBatchedLuFactor:
         rng = np.random.default_rng(2)
         b, f = 3, 12
         A = _chord(rng, b, f)
-        lu, perm = pi.batched_lu_factor(A, interpret=True)
+        lu, perm = pi.unpack_lu(*pi.batched_lu_factor(A, interpret=True), b, f)
         lu = np.asarray(lu)
         L = np.tril(lu, -1) + np.eye(f)
         U = np.triu(lu)
@@ -135,7 +135,7 @@ class TestBatchedLuFactor:
 
     def test_pivoting_handles_zero_diagonal(self):
         A = jnp.asarray([[[0.0, 1.0], [1.0, 0.0]]], jnp.float32)
-        lu, perm = pi.batched_lu_factor(A, interpret=True)
+        lu, perm = pi.unpack_lu(*pi.batched_lu_factor(A, interpret=True), 1, 2)
         np.testing.assert_array_equal(np.asarray(perm), [[1, 0]])
 
 
@@ -195,7 +195,8 @@ class TestLargeSystemsWithRowSwaps:
         shift = np.roll(np.eye(f), 1, axis=0)
         A = jnp.asarray(4.0 * shift + 0.1 * rng.standard_normal((b, f, f)), jnp.float32)
         r_lu, r_p = ref.batched_lu_factor(A)
-        p_lu, p_p = pi.batched_lu_factor(A, interpret=True)
+        p_op = pi.batched_lu_factor(A, interpret=True)
+        p_lu, p_p = pi.unpack_lu(*p_op, b, f)
         assert (np.asarray(p_p) != np.arange(f)).any(axis=1).all()
         np.testing.assert_array_equal(np.asarray(r_p), np.asarray(p_p))
         np.testing.assert_allclose(r_lu, p_lu, rtol=1e-4, atol=1e-4)
@@ -203,7 +204,7 @@ class TestLargeSystemsWithRowSwaps:
         active = jnp.asarray([True, False, True])
         scale = jnp.ones((b, f), jnp.float32)
         rk, rn = ref.fused_newton_iter(r_lu, r_p, k, fk, active, scale)
-        pk, pn = pi.fused_newton_iter(p_lu, p_p, k, fk, active, scale, interpret=True)
+        pk, pn = pi.fused_newton_iter(*p_op, k, fk, active, scale, interpret=True)
         np.testing.assert_allclose(rk, pk, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(rn, pn, rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(pk)[1], np.asarray(k)[1])

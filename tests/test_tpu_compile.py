@@ -14,7 +14,10 @@ here runs: results are checked by the interpret-mode parity suites.
 
 from __future__ import annotations
 
+import base64
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,11 +74,15 @@ def pallas_backend():
     ops.set_backend(old)
 
 
-def _compile(fn, specs, sharding):
+def _lower(fn, specs, sharding):
     placed = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), specs
     )
-    compiled = jax.jit(fn).lower(*placed).compile()
+    return jax.jit(fn).lower(*placed)
+
+
+def _compile(fn, specs, sharding):
+    compiled = _lower(fn, specs, sharding).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -139,8 +146,8 @@ def _op_case(name, w):
     if name == "batched_lu_factor":
         return op, (_f32(B, w, w),)
     if name == "fused_newton_iter":
-        perm = jax.ShapeDtypeStruct((B, w), jnp.int32)
-        return op, (_f32(B, w, w), perm, _f32(B, w), _f32(B, w), _bool(B), _f32(B, w))
+        lut, perm = jax.eval_shape(impl.batched_lu_factor, _f32(B, w, w))
+        return op, (lut, perm, _f32(B, w), _f32(B, w), _bool(B), _f32(B, w))
     if name == "fused_event_detect":
         directions = tuple((-1.0, 0.0, 1.0)[i % 3] for i in range(w))
         return (
@@ -202,12 +209,64 @@ def test_dopri5_dense_solve_compiles_for_v5e(one_chip, pallas_backend, fused):
 
 @pytest.mark.parametrize("fused", [False, True])
 def test_kvaerno5_solve_compiles_for_v5e(one_chip, pallas_backend, fused):
+    """Fused or not, a DIRK solve factors the chord matrix once per step
+    attempt and substitutes in every Newton iteration: no per-iteration
+    elimination (``_linsolve_kernel``) in the program."""
     def solve(y0):
         return solve_ivp(allen_cahn, y0, None, t_start=0.0, t_end=1.0,
                          method="kvaerno5", rtol=1e-4, atol=1e-7,
                          args=float(129**2), max_steps=4000, fused=fused)
 
-    _compile(solve, (_f32(512, 128),), one_chip)
+    lowered = _lower(solve, (_f32(512, 128),), one_chip)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    # Pallas names each kernel after its function.
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    assert {"_lu_factor_kernel", "_newton_iter_kernel"} <= kernels
+    assert not kernels & {"_linsolve_kernel", "_newton_update_kernel"}
+
+
+def _computations(hlo_text):
+    """The instruction lines of each computation of compiled HLO text."""
+    comps = []
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            comps.append([])
+        elif comps and line.startswith("  "):
+            comps[-1].append(line.strip().removeprefix("ROOT "))
+    return comps
+
+
+def _runs_kernel(line, kernel):
+    """Whether a compiled ``tpu_custom_call`` line runs the named kernel."""
+    at = line.find("backend_config=")
+    if 'custom_call_target="tpu_custom_call"' not in line or at < 0:
+        return False
+    config, _ = json.JSONDecoder().raw_decode(line, at + len("backend_config="))
+    return kernel.encode() in base64.b64decode(config["custom_call_config"]["body"])
+
+
+@pytest.mark.parametrize("f", [3, 128])
+def test_newton_loop_writes_no_factor_sized_array(one_chip, pallas_backend, f):
+    """The factors are laid out once per factorisation: the Newton loop body
+    reads them from its carry and writes nothing of their size, at a width
+    the 8-row tile pads (3, Robertson) and at one it does not (128)."""
+    def solve(y0):
+        return solve_ivp(allen_cahn, y0, None, t_start=0.0, t_end=1.0,
+                         method="kvaerno5", rtol=1e-4, atol=1e-7,
+                         args=float((f + 1) ** 2), max_steps=4000)
+
+    hlo = _compile(solve, (_f32(512, f),), one_chip).as_text()
+    bodies = [lines for lines in _computations(hlo)
+              if any(_runs_kernel(ln, "_newton_iter_kernel") for ln in lines)]
+    assert bodies
+    for lines in bodies:
+        kernel = next(ln for ln in lines if _runs_kernel(ln, "_newton_iter_kernel"))
+        # The kernel's first operand is the factors, as it reads them.
+        factor = re.search(r"operand_layout_constraints=\{(\w+\[[\d,]+\])", kernel)[1] + "{"
+        written = [ln for ln in lines
+                   if ln.split(" = ", 1)[-1].startswith(factor)
+                   and not re.search(r"\} (parameter|get-tuple-element|bitcast)\(", ln)]
+        assert not written, written
 
 
 def test_two_event_solve_compiles_for_v5e(one_chip, pallas_backend):
