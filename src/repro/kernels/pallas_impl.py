@@ -16,6 +16,15 @@ torchode fast, re-thought for the TPU memory hierarchy:
   - ``batched_linsolve``: per-instance dense Gauss-Jordan solve (with partial
     pivoting) for the implicit steppers' Newton systems, one batch tile per
     program with the full matrix resident in VMEM.
+  - ``batched_lu_factor`` + ``fused_newton_iter``: the factor-once Newton
+    path.  The LU kernel factors (8, n, n) batch tiles; one transpose per
+    factorisation then lays the factors out with INSTANCES ON LANES,
+    ``(n, n, bq)``, so that factor column j of a block of L instances is one
+    aligned (n, L) slab.  L is 128 once the 8-padded batch fills a lane
+    tile (the batch padded to a multiple of 128 with identity instances),
+    else the whole 8-padded batch.  Each Newton iteration is then one
+    substitution launch whose column steps are independent vreg ops over
+    (n, L) vectors, with no cross-lane reduction.
   - ``masked_newton_update``: the masked Newton commit fused with the
     per-instance scaled update norm (the inner-iteration analogue of
     ``error_norm``).
@@ -35,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
 
@@ -514,14 +524,24 @@ def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
     perm_out[...] = perm
 
 
+def _lane_block(bp):
+    """Instances per substitution program: a full lane tile once the
+    8-padded batch fills one, else the whole (small) batch as one
+    full-extent block, so a small batch reads no padded factors."""
+    return BF if bp >= BF else bp
+
+
 def batched_lu_factor(A, *, interpret=False):
     """Returns the operator in ``fused_newton_iter``'s layout, built once per
     factorisation so that no Newton iteration writes anything of size n^2:
-    the packed factors TRANSPOSED, ``lut[i, c, r] == lu[i, r, c]``, as
-    ``(bp, fr, fr)`` with the batch and both factor axes padded to the
-    8-sublane tile (identity on the padded block and on padded instances),
-    and the ``(bp, fr)`` int32 permutation (identity on padded entries).
-    ``unpack_lu`` reads it back in ``lax.linalg.lu``'s layout."""
+    the packed factors with INSTANCES ON LANES, ``lut[c, r, i] == lu[i, r,
+    c]``, as ``(fr, fr, bq)``, so factor column ``c`` of a block of instances
+    is one contiguous ``(fr, L)`` slab (rows on sublanes); and the ``(fr,
+    bq)`` int32 permutation.  ``fr`` is n padded to the 8-sublane tile; the
+    lane block ``L`` is 128 when the 8-padded batch ``bp`` fills a lane tile
+    (``bq`` then pads it to a multiple of 128) and ``bp`` itself otherwise.
+    Padded rows and padded instances carry the identity.  ``unpack_lu``
+    reads it back in ``lax.linalg.lu``'s layout."""
     b, f = A.shape[0], A.shape[1]
     # Same padding plan as ``batched_linsolve``: rows to the 8-sublane
     # layout, columns to the lane dimension, identity on the padded diagonal
@@ -547,107 +567,181 @@ def batched_lu_factor(A, *, interpret=False):
         ],
         interpret=interpret,
     )(Ap)
-    # The substitution kernel reads factor columns as sublane rows.
-    return jnp.swapaxes(lu[:, :, :fr], 1, 2), perm
+    # Instances past bp up to the lane block's multiple: identity factors
+    # and permutation.  Then one transpose puts instances on lanes.
+    bq = _cdiv(bp_, _lane_block(bp_)) * _lane_block(bp_)
+    extra = (jnp.arange(bq) >= bp_)[:, None, None]
+    lu = jnp.where(extra & (rr == jnp.arange(fr)[None, :]), 1.0,
+                   _pad_to(lu[:, :, :fr], 0, bq))
+    perm = jnp.where(extra[:, :, 0], jnp.arange(fr, dtype=jnp.int32)[None, :],
+                     _pad_to(perm, 0, bq))
+    return jnp.transpose(lu, (2, 1, 0)), perm.T
 
 
 def unpack_lu(lut, perm, b, f):
     """``batched_lu_factor``'s operator as ``lax.linalg.lu`` lays it out:
     the ``(b, f, f)`` packed factors and the ``(b, f)`` permutation."""
-    return jnp.swapaxes(lut, 1, 2)[:b, :f, :f], perm[:b, :f]
+    return jnp.transpose(lut, (2, 1, 0))[:b, :f, :f], perm.T[:b, :f]
 
 
 # ----------------------------------------------------------- fused newton iter
 
+# Bytes of one buffered block of factor columns in the substitution kernel:
+# at n = 128 and a 128-instance lane block the whole factor set (8 MiB) is
+# one block, so the kernel reads it once.
+_FACTOR_BLOCK_BYTES = 8 << 20
+
+
+def _factor_chunks(R, L, itemsize):
+    """``(cc, nc)``: factor columns per grid step and the number of steps,
+    the largest divisor ``cc`` of ``R`` whose ``(cc, R, L)`` block fits
+    ``_FACTOR_BLOCK_BYTES``."""
+    cc = max([d for d in range(1, R + 1)
+              if R % d == 0 and d * R * L * itemsize <= _FACTOR_BLOCK_BYTES] or [1])
+    return cc, R // cc
+
+
+# Steps per loop trip where the trip count is static.  Mosaic unrolls a
+# loop only fully; four steps a trip took the substitution launch at
+# n = 128 from 20.2 to 16.4 us on a v5e.
+_UNROLL = 4
+
+
+def _column_loop(m, step, x):
+    """``fori_loop(0, m, step, x)``, ``_UNROLL`` steps a trip for a static m."""
+    if not isinstance(m, int):
+        return jax.lax.fori_loop(0, m, step, x)
+
+    def trip(q, x):
+        for r in range(_UNROLL):
+            x = step(q * _UNROLL + r, x)
+        return x
+
+    x = jax.lax.fori_loop(0, m // _UNROLL, trip, x)
+    for t in range(m - m % _UNROLL, m):
+        x = step(t, x)
+    return x
+
 
 def _newton_iter_kernel(
-    lut_ref, perm_ref, k_ref, fk_ref, act_ref, scale_ref, k_out, res_out,
-    *, n, n_feat,
+    lut_ref, perm_ref, k_ref, fk_ref, act_ref, scale_ref, k_out, res_out, x_ref,
+    *, n, n_feat, cc, nc,
 ):
-    """One whole chord-Newton iteration against the prefactored LU, as ONE
-    program per batch tile: residual, permutation scatter, forward (unit
-    lower) and backward (upper) substitution, the masked commit and the
-    scaled-RMS convergence norm -- the fusion of ``batched_linsolve`` +
+    """One whole chord-Newton iteration against the prefactored LU, for one
+    block of L instances: residual, permutation gather, forward (unit lower)
+    and backward (upper) substitution, the masked commit and the scaled-RMS
+    convergence norm -- the fusion of ``batched_linsolve`` +
     ``masked_newton_update`` with the elimination already paid for.
 
-    Substitution is COLUMN-oriented against the TRANSPOSED factors: factor
-    column j is row j of ``lut_ref``, a dynamic sublane-axis load from VMEM
-    (Mosaic lowers no lane-axis dynamic slice), and each fori iteration does
-    O(R) vector work, so a whole triangular solve is O(n^2) -- this is what
-    makes the per-iteration launch strictly cheaper than the O(n^3)
-    elimination it replaces.  The padded tail never mixes in: padded
-    residual entries are 0 and real-row padded-column factors are 0.
+    INSTANCES ARE ON THE LANES and rows on sublanes: every vector is an
+    ``(R, L)`` array, factor column j of the block is the aligned ``(R, L)``
+    slab ``lut_ref[j]``, and x[j] for all L instances is row j of the VMEM
+    scratch ``x_ref`` (a dynamic sublane load), so a column step is a few
+    independent vreg ops on R/8 vregs with no cross-lane reduction.  Each
+    element of x sees the operations of a plain column-oriented
+    substitution, in its order; only the norm's sum runs along sublanes.
+
+    The grid is (instance block, phase, column chunk): phase 0 runs the
+    forward substitution over the chunks in order, phase 1 the backward one
+    in reverse, with x carried in ``x_ref``.  When the factors of a block
+    fit one chunk (``nc == 1``) both phases read the same block and it is
+    fetched once.  Only the true n columns are visited: padded columns are
+    identity and padded residual entries 0.
     """
-    bt, _, R = lut_ref.shape
-    perm = perm_ref[...]  # (BB, R) int32
-    k = k_ref[...]  # (BB, R)
-    g = k - fk_ref[...]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)
-    src3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, R), 2)
+    p = pl.program_id(1)
+    c = pl.program_id(2)
+    R, L = x_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+    if nc == 1:  # one chunk: static trip counts, so the loops unroll
+        j0, m = 0, n
+    else:
+        j0 = (c + p * (nc - 1 - 2 * c)) * cc  # first column of this chunk
+        m = jnp.clip(n - j0, 0, cc)  # its true columns
 
-    # permutation row-gather: x[r] = g[perm[r]] (one-hot, no dynamic gathers)
-    x = jnp.sum(jnp.where(perm[:, :, None] == src3, g[:, None, :], 0.0), axis=2)
+    @pl.when((p == 0) & (c == 0))
+    def _residual():  # x[r] = g[perm[r]], g = k - fk, one source row at a time
+        perm = perm_ref[...]
 
-    def col_of(j):  # factor column j, (BB, R)
-        return lut_ref[:, pl.ds(j, 1), :][:, 0, :]
+        def gather(s, x):
+            g_s = k_ref[pl.ds(s, 1), :] - fk_ref[pl.ds(s, 1), :]
+            return jnp.where(perm == s, g_s, x)
 
-    def at(j, v):  # extract entry j of a (BB, R) vector as (BB, 1)
-        return jnp.sum(jnp.where(rows == j, v, 0.0), axis=1, keepdims=True)
+        x_ref[...] = _column_loop(n, gather, jnp.zeros((R, L), x_ref.dtype))
 
-    def fwd(j, x):  # unit lower: x[i > j] -= L[i, j] * x[j]
-        return jnp.where(rows > j, x - col_of(j) * at(j, x), x)
+    @pl.when(p == 0)
+    def _forward():
+        def fwd(t, x):  # unit lower: x[i > j] -= L[i, j] * x[j]
+            j = j0 + t
+            x = jnp.where(rows > j, x - lut_ref[t] * x_ref[pl.ds(j, 1), :], x)
+            x_ref[...] = x
+            return x
 
-    x = jax.lax.fori_loop(0, n, fwd, x)
+        _column_loop(m, fwd, x_ref[...])
 
-    def bwd(t, x):  # upper: x[j] /= U[j, j]; then x[i < j] -= U[i, j] * x[j]
-        j = n - 1 - t
-        Ucol = col_of(j)
-        xj = at(j, x) / at(j, Ucol)
-        return jnp.where(rows == j, xj, jnp.where(rows < j, x - Ucol * xj, x))
+    @pl.when(p == 1)
+    def _backward():
+        def bwd(t, x):  # upper: x[j] /= U[j, j]; then x[i < j] -= U[i, j] * x[j]
+            jl = m - 1 - t
+            j = j0 + jl
+            xj = x_ref[pl.ds(j, 1), :] / lut_ref[jl, pl.ds(j, 1), :]
+            x = jnp.where(rows == j, xj, jnp.where(rows < j, x - lut_ref[jl] * xj, x))
+            x_ref[...] = x
+            return x
 
-    delta = jax.lax.fori_loop(0, n, bwd, x)
+        _column_loop(m, bwd, x_ref[...])
 
-    active = act_ref[...] != 0  # (BB, 1)
-    k_out[...] = jnp.where(active, k - delta, k)
-    r = delta / scale_ref[...]
-    res_out[...] = jnp.sqrt(jnp.sum(r * r, axis=1, keepdims=True) / n_feat)
+    @pl.when((p == 1) & (c == nc - 1))
+    def _commit():
+        delta = x_ref[...]
+        k = k_ref[...]
+        k_out[...] = jnp.where(act_ref[...] != 0, k - delta, k)
+        r = delta / scale_ref[...]
+        res_out[...] = jnp.sqrt(jnp.sum(r * r, axis=0, keepdims=True) / n_feat)
 
 
 def fused_newton_iter(lut, perm, k, fk, active, scale, *, interpret=False):
-    """``lut, perm`` is ``batched_lu_factor``'s operator, used as laid out:
-    the padded factor block is identity and the padded permutation entries
-    map to themselves, so padded residual entries stay 0."""
+    """``lut, perm`` is ``batched_lu_factor``'s operator, used as laid out.
+    The ``(b, f)`` vectors move to the kernel's ``(fr, bq)`` layout
+    (instances on lanes) and back: small XLA transposes, nothing of the
+    factors' size.  Padded entries are 0 (scale 1, active False), so padded
+    cells add 0 to the sum of squares, which is divided by the TRUE f.
+
+    VMEM: two buffers of one ``(cc, fr, L)`` factor block (at most
+    ``_FACTOR_BLOCK_BYTES`` each) plus 8 MiB for the vectors and the
+    compiler's own scratch: 24 MiB at f = 128 with 128-instance blocks."""
     b, f = k.shape
-    bp_, fr, _ = lut.shape
+    _, R, bq = lut.shape
+    L = _lane_block(bq)
+    cc, nc = _factor_chunks(R, L, lut.dtype.itemsize)
     scale = jnp.broadcast_to(jnp.asarray(scale, k.dtype), (b, f))
-    # Padded deltas are 0 and padded scales 1 -> padded cells add 0 to the
-    # sum of squares; divide by the TRUE feature count.
-    kp = _pad_to(_pad_to(k, 0, BB), 1, BB)
-    fkp = _pad_to(_pad_to(fk, 0, BB), 1, BB)
-    sp = _pad_to(_pad_to(scale, 0, BB, value=1), 1, BB, value=1)
-    ap = _pad_to(active.astype(jnp.int32)[:, None], 0, BB)
+
+    def lanes(v, value=0):  # (b, f) -> (R, bq)
+        return jnp.pad(v.T, ((0, R - f), (0, bq - b)), constant_values=value)
+
+    vec = pl.BlockSpec((R, L), lambda i, p, c: (0, i))
+    row = pl.BlockSpec((1, L), lambda i, p, c: (0, i))
     k_new, res = pl.pallas_call(
-        functools.partial(_newton_iter_kernel, n=f, n_feat=float(f)),
-        grid=(bp_ // BB,),
+        functools.partial(_newton_iter_kernel, n=f, n_feat=float(f), cc=cc, nc=nc),
+        grid=(bq // L, 2, nc),
         in_specs=[
-            pl.BlockSpec((BB, fr, fr), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-            pl.BlockSpec((BB, 1), lambda i: (i, 0)),
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
+            pl.BlockSpec((cc, R, L), lambda i, p, c: (c + p * (nc - 1 - 2 * c), 0, i)),
+            vec, vec, vec, row, vec,
         ],
-        out_specs=[
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-            pl.BlockSpec((BB, 1), lambda i: (i, 0)),
-        ],
+        out_specs=[vec, row],
         out_shape=[
-            jax.ShapeDtypeStruct((bp_, fr), k.dtype),
-            jax.ShapeDtypeStruct((bp_, 1), k.dtype),
+            jax.ShapeDtypeStruct((R, bq), k.dtype),
+            jax.ShapeDtypeStruct((1, bq), k.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((R, L), k.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * cc * R * L * lut.dtype.itemsize + (8 << 20),
+        ),
         interpret=interpret,
-    )(lut, perm, kp, fkp, ap, sp)
-    return k_new[:b, :f], res[:b, 0]
+    )(lut, perm, lanes(k), lanes(fk),
+      jnp.pad(active.astype(jnp.int32)[None, :], ((0, 0), (0, bq - b))),
+      lanes(scale, value=1))
+    return k_new[:f, :b].T, res[0, :b]
 
 
 # --------------------------------------------------------- masked newton update

@@ -115,8 +115,9 @@ def batched_lu_factor(A):
     ``fused_newton_iter`` launch back-substitutes against these factors
     instead of re-eliminating the same matrix.  The pair is an operator for
     the same backend's ``fused_newton_iter``: the Pallas backend returns it
-    already in its substitution kernel's padded, transposed layout
-    (``pallas_impl.unpack_lu`` reads that back as this one).
+    already in its substitution kernel's layout, padded, with instances on
+    the last (lane) axis, ``(fr, fr, bq)`` factors and an ``(fr, bq)``
+    permutation (``pallas_impl.unpack_lu`` reads that back as this one).
 
     ``lax.linalg.lu`` is the exact factorization ``jnp.linalg.solve`` (and
     hence ``batched_linsolve``) performs internally, so the factor +

@@ -2,6 +2,7 @@
 pure-jnp oracles -- runs without optional deps (no hypothesis), so the
 implicit solver's kernel contract is always checked."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -211,3 +212,90 @@ class TestLargeSystemsWithRowSwaps:
         delta = np.asarray(k) - np.asarray(pk)
         res = np.einsum("bij,bj->bi", np.asarray(A), delta) - np.asarray(k - fk)
         np.testing.assert_allclose(res[[0, 2]], 0.0, atol=1e-4)
+
+
+def _column_substitution(lu, perm, k, fk, active, scale):
+    """One chord-Newton iteration as a plain column-oriented substitution
+    on ``lax.linalg.lu``'s layout, in the kernel's operation order: gather,
+    ``x[i > j] -= L[i, j] * x[j]`` for j ascending, then ``x[j] /= U[j, j]``
+    and ``x[i < j] -= U[i, j] * x[j]`` for j descending."""
+    b, f = k.shape
+    x = jnp.take_along_axis(k - fk, perm, axis=1)
+    cols = jnp.arange(f)[None, :]
+
+    def fwd(j, x):
+        return jnp.where(cols > j, x - lu[:, :, j] * x[:, j][:, None], x)
+
+    def bwd(t, x):
+        j = f - 1 - t
+        xj = x[:, j][:, None] / lu[:, j, j][:, None]
+        return jnp.where(cols == j, xj, jnp.where(cols < j, x - lu[:, :, j] * xj, x))
+
+    x = jax.lax.fori_loop(0, f, bwd, jax.lax.fori_loop(0, f, fwd, x))
+    r = x / scale
+    return jnp.where(active[:, None], k - x, k), jnp.sqrt(jnp.sum(r * r, axis=1) / f)
+
+
+def _newton_case(b, f, seed):
+    rng = np.random.default_rng(seed)
+    A = _chord(rng, b, f)
+    k, fk = [jnp.asarray(rng.standard_normal((b, f)), jnp.float32) for _ in range(2)]
+    active = jnp.asarray(rng.uniform(size=(b,)) > 0.4)
+    scale = jnp.asarray(np.abs(rng.standard_normal((b, f))) + 0.3, jnp.float32)
+    return A, k, fk, active, scale
+
+
+# Both lane-block widths (a full-extent block of the 8-padded batch below
+# 128, 128-instance blocks from there on) and identity-padded instances.
+LANE_SHAPES = [(1, 1), (5, 3), (8, 128), (127, 17), (128, 128), (129, 3), (256, 128)]
+
+
+class TestInstancesOnLanes:
+    """The substitution kernel with instances on lanes computes each element
+    exactly as a column-oriented substitution does; only the norm's sum
+    runs in another order."""
+
+    @pytest.mark.parametrize("b,f", LANE_SHAPES)
+    def test_bitwise_column_substitution(self, b, f):
+        A, k, fk, active, scale = _newton_case(b, f, 11 * b + f)
+        op = pi.batched_lu_factor(A, interpret=True)
+        pk, pn = pi.fused_newton_iter(*op, k, fk, active, scale, interpret=True)
+        lu, perm = pi.unpack_lu(*op, b, f)
+        rk, rn = jax.jit(_column_substitution)(lu, perm, k, fk, active, scale)
+        np.testing.assert_array_equal(np.asarray(pk), np.asarray(rk))
+        np.testing.assert_array_max_ulp(np.asarray(pn), np.asarray(rn), maxulp=1)
+
+    @pytest.mark.parametrize("b,f", LANE_SHAPES)
+    def test_operator_layout(self, b, f):
+        """Factor column c of an instance block is one (fr, L) slab with
+        instances on lanes; padded instances and rows carry the identity."""
+        A = _chord(np.random.default_rng(b + f), b, f)
+        lut, perm = pi.batched_lu_factor(A, interpret=True)
+        fr, bp = -(-f // 8) * 8, -(-b // 8) * 8
+        bq = bp if bp < 128 else -(-bp // 128) * 128
+        assert lut.shape == (fr, fr, bq) and perm.shape == (fr, bq)
+        lu, p = pi.unpack_lu(lut, perm, b, f)
+        np.testing.assert_array_equal(np.asarray(lut)[:f, :f, :b],
+                                      np.transpose(np.asarray(lu), (2, 1, 0)))
+        np.testing.assert_array_equal(np.asarray(perm)[:f, :b], np.asarray(p).T)
+        eye = np.eye(fr, dtype=np.float32)
+        for i in range(b, bq):
+            np.testing.assert_array_equal(np.asarray(lut)[:, :, i], eye)
+            np.testing.assert_array_equal(np.asarray(perm)[:, i], np.arange(fr))
+        np.testing.assert_array_equal(np.asarray(lut)[f:, f:, :b],
+                                      np.broadcast_to(eye[f:, f:, None], (fr - f, fr - f, b)))
+
+    @pytest.mark.parametrize("b,f,budget", [(9, 64, 16 << 10), (130, 17, 40 << 10),
+                                            (256, 128, 2 << 20)])
+    def test_column_chunks_match_one_block(self, monkeypatch, b, f, budget):
+        """Factors streamed in column chunks (forward in order, backward in
+        reverse) give the one-block result bitwise."""
+        A, k, fk, active, scale = _newton_case(b, f, 3 * b + f)
+        op = pi.batched_lu_factor(A, interpret=True)
+        whole = pi.fused_newton_iter(*op, k, fk, active, scale, interpret=True)
+        monkeypatch.setattr(pi, "_FACTOR_BLOCK_BYTES", budget)
+        _, R, bq = op[0].shape
+        assert pi._factor_chunks(R, min(bq, 128), 4)[1] > 1
+        chunked = pi.fused_newton_iter(*op, k, fk, active, scale, interpret=True)
+        for w, c in zip(whole, chunked):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(c))

@@ -186,13 +186,44 @@ def test_registry_op_compiles_for_v5e(one_chip, name, width):
     _compile(fn, specs, one_chip)
 
 
+def _factor_op_specs(b, n):
+    """Shapes of ``batched_lu_factor`` and ``fused_newton_iter`` at batch b."""
+    lut, perm = jax.eval_shape(pallas_impl.batched_lu_factor, _f32(b, n, n))
+    return {
+        "batched_lu_factor": (_f32(b, n, n),),
+        "fused_newton_iter": (lut, perm, _f32(b, n), _f32(b, n), _bool(b), _f32(b, n)),
+    }
+
+
+FACTOR_CASES = [(name, b, n) for name in ("batched_lu_factor", "fused_newton_iter")
+                for b in (8, 256) for n in N_WIDTHS]
+
+
+@pytest.mark.parametrize("name,b,n", FACTOR_CASES,
+                         ids=[f"{name}-b{b}-n{n}" for name, b, n in FACTOR_CASES])
+def test_factor_ops_compile_at_both_lane_blocks(one_chip, name, b, n):
+    """A batch below one lane tile is one full-extent instance block (8);
+    from 128 on, instances go in 128-lane blocks (256: two of them)."""
+    _compile(getattr(pallas_impl, name), _factor_op_specs(b, n)[name], one_chip)
+
+
 def test_lu_kernels_fit_vmem_at_n128(one_chip):
-    """The LU kernels keep a (BB, n, n) tile resident; at n = 128 the whole
-    factor + iteration program must fit the chip's memory."""
-    for name in ("batched_lu_factor", "fused_newton_iter"):
-        fn, specs = _op_case(name, 128)
-        mem = _compile(fn, specs, one_chip).memory_analysis()
+    """The LU kernel keeps a (BB, n, n) tile resident, the substitution
+    kernel one (n, n, 128) block of factors (8 MiB at n = 128): that is its
+    whole factor set, so both phases read the one block.  At n = 128 the
+    whole factor + iteration program must fit the chip's memory."""
+    assert pallas_impl._factor_chunks(128, 128, 4) == (128, 1)
+    for name, specs in _factor_op_specs(256, 128).items():
+        mem = _compile(getattr(pallas_impl, name), specs, one_chip).memory_analysis()
         assert mem.temp_size_in_bytes < 4 << 30
+
+
+def test_newton_iter_streams_column_chunks_at_n256(one_chip):
+    """Past one block the factors stream in column chunks (forward in
+    order, backward in reverse): n = 256 takes four."""
+    assert pallas_impl._factor_chunks(256, 128, 4) == (64, 4)
+    _compile(pallas_impl.fused_newton_iter, _factor_op_specs(256, 256)["fused_newton_iter"],
+             one_chip)
 
 
 @pytest.mark.parametrize("fused", [False, True])
