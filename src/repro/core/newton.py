@@ -4,33 +4,24 @@ This is the paper's per-instance principle pushed down into the *inner*
 nonlinear solve: every ODE instance in the batch iterates its own Newton
 sequence and terminates independently through a convergence mask, exactly the
 way the outer loop freezes finished instances.  One global ``while_loop``
-iteration performs one batched vector-field evaluation and one batched dense
-linear solve -- instances that already converged (or failed) stop updating
-but keep riding along (the inner-loop analogue of torchode's "overhanging
-evaluations"), so there is never a host sync or a per-instance Python loop.
+iteration performs one batched vector-field evaluation and one batched
+substitution against a prefactored LU -- instances that already converged (or
+failed) stop updating but keep riding along (the inner-loop analogue of
+torchode's "overhanging evaluations"), so there is never a host sync or a
+per-instance Python loop.
 
 The iteration is a *chord* Newton: the matrix ``M = I - dt*gamma*J`` is built
 once per solver step from a (possibly stale, per-instance refreshed) Jacobian
-and reused across all stages and iterations.  Two linear-algebra strategies
-share the loop:
-
-``operator`` path (factor once)
-    The caller factors ``M`` once per step via ``ops.batched_lu_factor``
-    (partial-pivoted LU) and every iteration runs ONE fused op,
-    ``ops.fused_newton_iter``: residual, permutation scatter, the two
-    triangular back-substitutions against the prefactored LU, masked commit,
-    and the scaled-RMS norm in a single launch.  ``DiagonallyImplicitRK``
-    takes this path on every step, fused or not.
-
-``M`` path
-    Each iteration runs a full batched dense solve against ``M``
-    (``ops.batched_linsolve``) followed by the masked commit + convergence
-    norm (``ops.masked_newton_update``).  This is the external-caller API:
-    no precomputation required, but an O(n^3) elimination per iteration.
+and reused across all stages and iterations.  ``M`` is factored once
+(``ops.batched_lu_factor``, partial-pivoted LU) and every iteration runs ONE
+fused op, ``ops.fused_newton_iter``: residual, permutation scatter, the two
+triangular back-substitutions against the factors, masked commit, and the
+scaled-RMS norm in a single launch.  ``DiagonallyImplicitRK`` factors once per
+step attempt and shares the factors across its stages.
 
 On the ref backend the LU composition reproduces ``jnp.linalg.solve``
 bitwise (it is the same ``lax.linalg.lu`` + triangular-solve sequence
-``solve`` lowers to), so both paths yield identical iterates.
+``solve`` lowers to), so each iterate is that of a dense solve against ``M``.
 """
 
 from __future__ import annotations
@@ -122,15 +113,17 @@ def newton_solve(
     instance with ``diverged`` set; the stepper reports that through the
     controller's reject path rather than poisoning the whole batch.
 
-    The linear solve comes from exactly one of two sources:
+    The chord operator comes from exactly one of two sources:
 
-    - ``M``: the chord matrix itself; each iteration runs a fresh batched
-      dense solve (``ops.batched_linsolve``).
+    - ``M``: the chord matrix itself, factored once on entry by
+      ``ops.batched_lu_factor`` under the active kernel backend.
     - ``operator``: the ``(lu, permutation)`` pair from
       ``ops.batched_lu_factor(M)``, in the kernel backend's own layout
-      (build it under the backend that runs the solve); each iteration
-      runs the single fused ``ops.fused_newton_iter`` launch against the
-      prefactored LU.
+      (build it under the backend that runs the solve), for callers that
+      share one factorisation across several solves.
+
+    Either way each iteration is the single fused ``ops.fused_newton_iter``
+    launch against the prefactored LU.
 
     All numeric knobs live on ``config`` (a :class:`NewtonConfig`); ``None``
     means the defaults.
@@ -139,6 +132,9 @@ def newton_solve(
         raise TypeError("newton_solve needs exactly one of M= or operator=")
     if scale is None:
         raise TypeError("newton_solve requires scale")
+    if operator is None:
+        operator = ops.batched_lu_factor(M)
+    lu, perm = operator
     cfg = config if config is not None else NewtonConfig()
     tol, max_iters, divergence_rate = cfg.tol, cfg.max_iters, cfg.divergence_rate
     b = k0.shape[0]
@@ -148,14 +144,8 @@ def newton_solve(
         return jnp.any(s.active) & (s.it < max_iters)
 
     def body(s: _NewtonState):
-        if operator is not None:
-            lu, perm = operator
-            k_new, res_norm = ops.fused_newton_iter(
-                lu, perm, s.k, eval_fn(s.k), s.active, scale)
-        else:
-            g = s.k - eval_fn(s.k)
-            delta = ops.batched_linsolve(M, g)
-            k_new, res_norm = ops.masked_newton_update(s.k, delta, s.active, scale)
+        k_new, res_norm = ops.fused_newton_iter(
+            lu, perm, s.k, eval_fn(s.k), s.active, scale)
         finite = jnp.isfinite(res_norm)
         conv_now = s.active & finite & (res_norm <= tol)
         div_now = s.active & (~finite | ((s.it > 0) & (res_norm > divergence_rate * s.prev_norm)))

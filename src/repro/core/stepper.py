@@ -26,7 +26,6 @@ pid_controller())`` or ``AutoDiffAdjoint("kvaerno5")``).
 
 from __future__ import annotations
 
-import warnings
 from typing import Any, NamedTuple
 
 import jax
@@ -348,9 +347,7 @@ class DiagonallyImplicitRK(AbstractStepper):
 
     All inner-solver knobs live on ONE object: pass
     ``newton=NewtonConfig(tol=..., max_iters=..., divergence_rate=...,
-    slow_iters=...)``.  The legacy loose kwargs (``newton_tol``,
-    ``max_newton_iters``, ``slow_iters``) are deprecated aliases that emit a
-    ``DeprecationWarning`` and cannot be combined with ``newton=``.
+    slow_iters=...)``.
 
     Statistics: ``n_f_evals`` (batched Newton evaluations, overhanging),
     ``n_newton_iters`` (per-instance inner iterations while running),
@@ -366,9 +363,6 @@ class DiagonallyImplicitRK(AbstractStepper):
         method: str | ButcherTableau = "kvaerno5",
         *,
         newton: NewtonConfig | None = None,
-        newton_tol: float | None = None,
-        max_newton_iters: int | None = None,
-        slow_iters: int | None = None,
     ):
         self.tableau = get_tableau(method) if isinstance(method, str) else method
         if not self.tableau.implicit:
@@ -376,45 +370,8 @@ class DiagonallyImplicitRK(AbstractStepper):
                 f"tableau {self.tableau.name!r} is explicit; use ExplicitRK"
             )
         self.gamma = self.tableau.diagonal  # validates the constant diagonal
-        legacy = {
-            "newton_tol": newton_tol,
-            "max_newton_iters": max_newton_iters,
-            "slow_iters": slow_iters,
-        }
-        used = [name for name, v in legacy.items() if v is not None]
-        if used:
-            if newton is not None:
-                raise TypeError(
-                    f"cannot combine newton= with legacy kwarg(s) {used}; "
-                    "put every knob on the NewtonConfig"
-                )
-            warnings.warn(
-                f"DiagonallyImplicitRK kwarg(s) {used} are deprecated; pass "
-                "newton=NewtonConfig(tol=..., max_iters=..., slow_iters=...) "
-                "instead",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            newton = NewtonConfig(
-                tol=newton_tol if newton_tol is not None else 1e-2,
-                max_iters=max_newton_iters if max_newton_iters is not None else 8,
-                slow_iters=slow_iters,
-            )
         self.newton = newton if newton is not None else NewtonConfig()
         freeze(self)
-
-    # The pre-NewtonConfig knob names, kept readable for callers/tests.
-    @property
-    def newton_tol(self) -> float:
-        return self.newton.tol
-
-    @property
-    def max_newton_iters(self) -> int:
-        return self.newton.max_iters
-
-    @property
-    def slow_iters(self) -> int:
-        return self.newton.effective_slow_iters
 
     def init_carry(self, term, t0, y0, f0, args) -> DIRKCarry:
         b, f = y0.shape

@@ -86,10 +86,8 @@ _OP_NAMES = (
     "fused_update",
     "error_norm",
     "interp_eval",
-    "batched_linsolve",
     "batched_lu_factor",
     "fused_newton_iter",
-    "masked_newton_update",
     "masked_bisect_refine",
     "fused_step",
     "fused_step_poly",

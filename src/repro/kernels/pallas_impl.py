@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the solver's hot-spot ops.
 
-Three kernels, mirroring the fused PyTorch kernels (einsum/addcmul) that make
+The kernels mirror the fused PyTorch kernels (einsum/addcmul) that make
 torchode fast, re-thought for the TPU memory hierarchy:
 
   - ``fused_update``: one HBM->VMEM pass over the stage tensor K produces BOTH
@@ -13,9 +13,6 @@ torchode fast, re-thought for the TPU memory hierarchy:
     block (grid is sequential on TPU), finalizing sqrt(mean) on the last tile.
   - ``interp_eval``: masked Horner evaluation of the dense-output cubic into the
     (aliased) output buffer -- torchode's "evaluation tracking" hot spot.
-  - ``batched_linsolve``: per-instance dense Gauss-Jordan solve (with partial
-    pivoting) for the implicit steppers' Newton systems, one batch tile per
-    program with the full matrix resident in VMEM.
   - ``batched_lu_factor`` + ``fused_newton_iter``: the factor-once Newton
     path.  The LU kernel factors (8, n, n) batch tiles; one transpose per
     factorisation then lays the factors out with INSTANCES ON LANES,
@@ -25,9 +22,6 @@ torchode fast, re-thought for the TPU memory hierarchy:
     else the whole 8-padded batch.  Each Newton iteration is then one
     substitution launch whose column steps are independent vreg ops over
     (n, L) vectors, with no cross-lane reduction.
-  - ``masked_newton_update``: the masked Newton commit fused with the
-    per-instance scaled update norm (the inner-iteration analogue of
-    ``error_norm``).
   - ``masked_bisect_refine``: one masked bisection step of the event-time
     localizer -- bracket halving fused with the Horner evaluation of the
     dense-output cubic at the new midpoint.
@@ -373,11 +367,11 @@ def masked_bisect_refine(coeffs, lo, hi, v_lo, v_mid, active, *, interpret=False
     return lo_n[:b, 0], hi_n[:b, 0], vlo_n[:b, 0], mid_n[:b, 0], y_mid[:b, :f]
 
 
-# ------------------------------------------------------- batched linear solve
+# ----------------------------------------------------- batched LU factorization
 
 
-def _linsolve_kernel(a_ref, b_ref, x_ref, *, n):
-    """Gauss-Jordan with partial pivoting, vectorized over the batch tile.
+def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
+    """Partial-pivoted LU factorization, vectorized over the batch tile.
 
     One program owns BB instances and their full (R, C) matrices in VMEM
     (R = rows padded to the 8-sublane layout, C = columns padded to the
@@ -386,96 +380,13 @@ def _linsolve_kernel(a_ref, b_ref, x_ref, *, n):
     masked reductions (Mosaic lowers no dynamic gathers or value slices), the
     pivot search with a max-reduction + first-match instead of argmax, so
     every op vectorizes.  Only the true n columns are eliminated: the padded
-    block is an identity that never mixes with real rows.
-    """
-    A = a_ref[...]  # (BB, R, C)
-    rhs = b_ref[...]  # (BB, R)
-    bt, R, C = A.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, R), 1)  # (BB, R)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, C), 1)  # (BB, C)
-    row3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 1)
-    col3 = jax.lax.broadcasted_iota(jnp.int32, (1, R, C), 2)
-
-    def body(i, carry):
-        A, rhs = carry
-        col = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
-        mag = jnp.where(rows >= i, jnp.abs(col), -1.0)
-        m = jnp.max(mag, axis=1, keepdims=True)
-        cand = mag == m
-        p = jnp.min(jnp.where(cand, rows, R), axis=1, keepdims=True)  # (BB, 1)
-        is_i = rows == i
-        is_p = rows == p
-        # (R, C)-shaped row masks come from the 3-D iota: Mosaic cannot
-        # reshape a bool vector, so is_i[:, :, None] does not lower.
-        is_i3 = row3 == i
-        is_p3 = row3 == p[:, :, None]
-        Ai = jnp.sum(jnp.where(is_i3, A, 0.0), axis=1)  # (BB, C)
-        Ap = jnp.sum(jnp.where(is_p3, A, 0.0), axis=1)
-        bi = jnp.sum(jnp.where(is_i, rhs, 0.0), axis=1, keepdims=True)  # (BB, 1)
-        bp = jnp.sum(jnp.where(is_p, rhs, 0.0), axis=1, keepdims=True)
-        # swap rows i <-> p (no-op when p == i: is_i wins and Ap == Ai)
-        A = jnp.where(
-            is_i3, Ap[:, None, :], jnp.where(is_p3, Ai[:, None, :], A)
-        )
-        rhs = jnp.where(is_i, bp, jnp.where(is_p, bi, rhs))
-        # normalize the pivot row, eliminate column i from every other row
-        piv = jnp.sum(jnp.where(cols == i, Ap, 0.0), axis=1, keepdims=True)  # (BB, 1)
-        prow = Ap / piv
-        pb = bp / piv
-        colnew = jnp.sum(jnp.where(col3 == i, A, 0.0), axis=2)  # (BB, R)
-        factor = jnp.where(is_i, 0.0, colnew)
-        A = A - factor[:, :, None] * prow[:, None, :]
-        rhs = rhs - factor * pb
-        A = jnp.where(is_i3, prow[:, None, :], A)
-        rhs = jnp.where(is_i, pb, rhs)
-        return A, rhs
-
-    _, rhs = jax.lax.fori_loop(0, n, body, (A, rhs))
-    x_ref[...] = rhs
-
-
-def batched_linsolve(A, rhs, *, interpret=False):
-    b, f = rhs.shape
-    # Rows only need the 8-sublane layout; columns are the lane dimension.
-    Ap = _pad_to(_pad_to(_pad_to(A, 0, BB), 1, BB), 2, BF)
-    bp_, fr, fc = Ap.shape
-    # The padded block must stay nonsingular: identity on the padded diagonal.
-    pad_eye = (
-        (jnp.arange(fr)[:, None] == jnp.arange(fc)[None, :])
-        & (jnp.arange(fr)[:, None] >= f)
-    ).astype(A.dtype)
-    Ap = Ap + pad_eye[None, :, :]
-    rhsp = _pad_to(_pad_to(rhs, 0, BB), 1, BB)
-    out = pl.pallas_call(
-        functools.partial(_linsolve_kernel, n=f),
-        grid=(bp_ // BB,),
-        in_specs=[
-            pl.BlockSpec((BB, fr, fc), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((BB, fr), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp_, fr), rhs.dtype),
-        interpret=interpret,
-    )(Ap, rhsp)
-    return out[:b, :f]
-
-
-# ----------------------------------------------------- batched LU factorization
-
-
-def _lu_factor_kernel(a_ref, lu_out, perm_out, *, n):
-    """Partial-pivoted LU factorization, vectorized over the batch tile.
-
-    Same memory plan as ``_linsolve_kernel`` (one program owns BB instances
-    with the full (R, C) matrix in VMEM, one-hot row/column extraction and
-    swap, pivot by max-reduction + first-match), but instead of eliminating
-    a right-hand side it stores the factors in place -- the unit-lower
-    multipliers below the diagonal, U on and above -- and tracks the row
-    permutation as a (BB, R) int32 vector (entry swaps mirror the row
-    swaps).  This runs ONCE per implicit solver step; every
-    ``fused_newton_iter`` launch then back-substitutes against the stored
-    factors, which is what turns the per-iteration O(n^3) elimination into
-    O(n^2) triangular solves.
+    block is an identity that never mixes with real rows.  The factors are
+    stored in place -- the unit-lower multipliers below the diagonal, U on
+    and above -- and the row permutation is tracked as a (BB, R) int32
+    vector (entry swaps mirror the row swaps).  This runs ONCE per implicit
+    solver step; every ``fused_newton_iter`` launch then back-substitutes
+    against the stored factors, so each Newton iteration costs O(n^2)
+    triangular solves and no O(n^3) elimination.
     """
     A = a_ref[...]  # (BB, R, C)
     bt, R, C = A.shape
@@ -543,9 +454,9 @@ def batched_lu_factor(A, *, interpret=False):
     Padded rows and padded instances carry the identity.  ``unpack_lu``
     reads it back in ``lax.linalg.lu``'s layout."""
     b, f = A.shape[0], A.shape[1]
-    # Same padding plan as ``batched_linsolve``: rows to the 8-sublane
-    # layout, columns to the lane dimension, identity on the padded diagonal
-    # so the padded block never pivots into the real rows.
+    # Rows to the 8-sublane layout, columns to the lane dimension, identity
+    # on the padded diagonal so the padded block never pivots into the real
+    # rows.
     Ap = _pad_to(_pad_to(_pad_to(A, 0, BB), 1, BB), 2, BF)
     bp_, fr, fc = Ap.shape
     rr = jnp.arange(fr)[:, None]
@@ -630,8 +541,7 @@ def _newton_iter_kernel(
     """One whole chord-Newton iteration against the prefactored LU, for one
     block of L instances: residual, permutation gather, forward (unit lower)
     and backward (upper) substitution, the masked commit and the scaled-RMS
-    convergence norm -- the fusion of ``batched_linsolve`` +
-    ``masked_newton_update`` with the elimination already paid for.
+    convergence norm, with the elimination already paid for.
 
     INSTANCES ARE ON THE LANES and rows on sublanes: every vector is an
     ``(R, L)`` array, factor column j of the block is the aligned ``(R, L)``
@@ -742,61 +652,6 @@ def fused_newton_iter(lut, perm, k, fk, active, scale, *, interpret=False):
       jnp.pad(active.astype(jnp.int32)[None, :], ((0, 0), (0, bq - b))),
       lanes(scale, value=1))
     return k_new[:f, :b].T, res[0, :b]
-
-
-# --------------------------------------------------------- masked newton update
-
-
-def _newton_update_kernel(k_ref, d_ref, act_ref, scale_ref, k_out, res_out, *, n_feat, nf_tiles):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        res_out[...] = jnp.zeros_like(res_out)
-
-    k = k_ref[...]
-    d = d_ref[...]
-    active = act_ref[...]  # (BB, 1) bool
-    k_out[...] = jnp.where(active, k - d, k)
-    r = d / scale_ref[...]
-    res_out[...] += jnp.sum(r * r, axis=1, keepdims=True)
-
-    @pl.when(j == nf_tiles - 1)
-    def _finalize():
-        res_out[...] = jnp.sqrt(res_out[...] / n_feat)
-
-
-def masked_newton_update(k, delta, active, scale, *, interpret=False):
-    b, f = k.shape
-    scale = jnp.broadcast_to(jnp.asarray(scale, k.dtype), (b, f))
-    # Padding is exact: padded deltas are 0 and padded scales 1, so padded
-    # cells add 0 to the sum of squares; we divide by the TRUE feature count.
-    kp = _pad_to(_pad_to(k, 0, BB), 1, BF)
-    dp = _pad_to(_pad_to(delta, 0, BB), 1, BF)
-    ap = _pad_to(active[:, None], 0, BB)
-    sp = _pad_to(_pad_to(scale, 0, BB, value=1), 1, BF, value=1)
-    bp_, fp = kp.shape
-    nf_tiles = fp // BF
-    k_new, res = pl.pallas_call(
-        functools.partial(_newton_update_kernel, n_feat=float(f), nf_tiles=nf_tiles),
-        grid=(bp_ // BB, nf_tiles),
-        in_specs=[
-            pl.BlockSpec((BB, BF), lambda i, j: (i, j)),
-            pl.BlockSpec((BB, BF), lambda i, j: (i, j)),
-            pl.BlockSpec((BB, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((BB, BF), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BB, BF), lambda i, j: (i, j)),
-            pl.BlockSpec((BB, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(kp.shape, k.dtype),
-            jax.ShapeDtypeStruct((bp_, 1), k.dtype),
-        ],
-        interpret=interpret,
-    )(kp, dp, ap, sp)
-    return k_new[:b, :f], res[:b, 0]
 
 
 # -------------------------------------------------------------- fused RK step
@@ -1430,17 +1285,11 @@ class _Impl:
     def interp_eval(self, coeffs, x, mask, out):
         return interp_eval(coeffs, x, mask, out, interpret=self._i)
 
-    def batched_linsolve(self, A, rhs):
-        return batched_linsolve(A, rhs, interpret=self._i)
-
     def batched_lu_factor(self, A):
         return batched_lu_factor(A, interpret=self._i)
 
     def fused_newton_iter(self, lut, perm, k, fk, active, scale):
         return fused_newton_iter(lut, perm, k, fk, active, scale, interpret=self._i)
-
-    def masked_newton_update(self, k, delta, active, scale):
-        return masked_newton_update(k, delta, active, scale, interpret=self._i)
 
     def masked_bisect_refine(self, coeffs, lo, hi, v_lo, v_mid, active):
         return masked_bisect_refine(coeffs, lo, hi, v_lo, v_mid, active, interpret=self._i)

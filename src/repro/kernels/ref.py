@@ -92,18 +92,6 @@ def hermite_coeffs(y0, y1, f0, f1, dt):
     return c0, c1, c2, c3
 
 
-def batched_linsolve(A, rhs):
-    """Batched dense linear solve: x s.t. A @ x = rhs, per instance.
-
-    A:   (b, f, f) Newton matrices (I - dt*gamma*J -- well conditioned for
-         any stable step size, diagonally dominant in the stiff limit)
-    rhs: (b, f)
-
-    Returns (b, f).  The inner hot spot of the masked-Newton layer.
-    """
-    return jnp.linalg.solve(A, rhs[..., None])[..., 0]
-
-
 def batched_lu_factor(A):
     """Batched partial-pivoted LU factorization: factor ONCE per solver step.
 
@@ -119,10 +107,9 @@ def batched_lu_factor(A):
     the last (lane) axis, ``(fr, fr, bq)`` factors and an ``(fr, bq)``
     permutation (``pallas_impl.unpack_lu`` reads that back as this one).
 
-    ``lax.linalg.lu`` is the exact factorization ``jnp.linalg.solve`` (and
-    hence ``batched_linsolve``) performs internally, so the factor +
-    back-substitution composition reproduces the unfused solve bitwise on
-    this backend.
+    ``lax.linalg.lu`` is the exact factorization ``jnp.linalg.solve``
+    performs internally, so the factor + back-substitution composition
+    reproduces ``jnp.linalg.solve`` bitwise on this backend.
     """
     lu, _, permutation = lax_linalg.lu(A)
     return lu, permutation
@@ -140,12 +127,14 @@ def fused_newton_iter(lu, perm, k, fk, active, scale):
     active: (b,) bool -- instances still iterating
     scale:  (b, f) error scale atol + rtol*|y| (may broadcast)
 
-    Returns ``(k_new, res_norm)`` exactly like ``masked_newton_update``; the
-    update solved here is ``delta = M^{-1} (k - fk)`` via the LU factors.
-    The triangular-solve sequence mirrors ``lax.linalg``'s own ``lu_solve``
+    Returns ``(k_new, res_norm)``: ``k - delta`` where active (``k``
+    elsewhere) and the (b,) RMS of ``delta / scale``, where the update
+    ``delta = M^{-1} (k - fk)`` is solved via the LU factors.  The
+    triangular-solve sequence mirrors ``lax.linalg``'s own ``lu_solve``
     lowering (permutation row-gather, unit-lower then upper solve), which is
-    what ``jnp.linalg.solve`` runs after factorizing -- so a solve composed of
-    ``batched_lu_factor`` + this op is bitwise-equal to ``batched_linsolve``.
+    what ``jnp.linalg.solve`` runs after factorizing -- so ``delta`` from
+    ``batched_lu_factor`` + this op is bitwise-equal to
+    ``jnp.linalg.solve(M, k - fk)``.
     """
     g = k - fk
     x = jnp.take_along_axis(g[..., None], perm[..., None], axis=-2)
@@ -153,24 +142,6 @@ def fused_newton_iter(lu, perm, k, fk, active, scale):
                                     unit_diagonal=True)
     x = lax_linalg.triangular_solve(lu, x, left_side=True, lower=False)
     delta = x[..., 0]
-    k_new = jnp.where(active[:, None], k - delta, k)
-    ratio = delta / scale
-    return k_new, jnp.sqrt(jnp.mean(ratio * ratio, axis=-1))
-
-
-def masked_newton_update(k, delta, active, scale):
-    """One fused masked Newton commit: apply the update only where an
-    instance's nonlinear solve is still active, and report the scaled RMS
-    norm of the update (the per-instance convergence measure).
-
-    k:      (b, f) current stage iterate
-    delta:  (b, f) Newton update (solution of the linearized system)
-    active: (b,) bool -- instances still iterating
-    scale:  (b, f) error scale atol + rtol*|y| (may broadcast)
-
-    Returns (k_new, res_norm): k - delta where active (k elsewhere), and the
-    (b,) RMS of delta/scale.
-    """
     k_new = jnp.where(active[:, None], k - delta, k)
     ratio = delta / scale
     return k_new, jnp.sqrt(jnp.mean(ratio * ratio, axis=-1))

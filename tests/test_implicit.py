@@ -280,31 +280,7 @@ class TestNewtonSubsystem:
 
 class TestNewtonConfigAPI:
     """The consolidated ``NewtonConfig`` surface: ``newton=`` is the one
-    configuration path, legacy kwargs are deprecated aliases, and
-    ``newton_solve`` is config-first."""
-
-    def test_legacy_kwargs_warn_and_alias(self):
-        with pytest.warns(DeprecationWarning, match="deprecated"):
-            legacy = DiagonallyImplicitRK("kvaerno3", newton_tol=1e-4,
-                                          max_newton_iters=11, slow_iters=3)
-        modern = DiagonallyImplicitRK(
-            "kvaerno3", newton=NewtonConfig(tol=1e-4, max_iters=11, slow_iters=3))
-        assert legacy.newton == modern.newton
-        # the read-only properties survive as views over the config
-        assert legacy.newton_tol == 1e-4
-        assert legacy.max_newton_iters == 11
-        assert legacy.slow_iters == 3
-
-    def test_partial_legacy_kwargs_fill_defaults(self):
-        with pytest.warns(DeprecationWarning):
-            st = DiagonallyImplicitRK("trbdf2", max_newton_iters=3)
-        assert st.newton == NewtonConfig(max_iters=3)
-        assert st.newton_tol == NewtonConfig().tol
-
-    def test_legacy_and_newton_conflict_raises(self):
-        with pytest.raises(TypeError, match="cannot combine"):
-            DiagonallyImplicitRK("kvaerno3", newton=NewtonConfig(),
-                                 newton_tol=1e-4)
+    configuration path and ``newton_solve`` is config-first."""
 
     def test_default_slow_iters_derives_from_budget(self):
         cfg = NewtonConfig(max_iters=8)
@@ -354,10 +330,8 @@ class TestNewtonConfigAPI:
                               operator=ops.batched_lu_factor(M),
                               scale=jnp.ones((b, f)), config=cfg)
         assert np.all(np.asarray(res_op.converged))
-        # bitwise on the ref backend (verified by test_fused_implicit); the
-        # interpret leg runs Gauss-Jordan vs LU, so allow rounding here
-        np.testing.assert_allclose(np.asarray(res_m.k), np.asarray(res_op.k),
-                                   rtol=1e-5, atol=1e-6)
+        # M= is factored by the same batched_lu_factor on entry: bitwise
+        np.testing.assert_array_equal(np.asarray(res_m.k), np.asarray(res_op.k))
         np.testing.assert_array_equal(np.asarray(res_m.n_iters),
                                       np.asarray(res_op.n_iters))
 

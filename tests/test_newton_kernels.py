@@ -7,15 +7,28 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import pallas_impl as pi, ref
+from repro.core import NewtonConfig, newton_solve
+from repro.kernels import ops, pallas_impl as pi, ref
 
 SHAPES = [(1, 1), (3, 5), (8, 128), (17, 300), (2, 1025), (9, 64)]
 
 
+def _lu_solve(A, rhs):
+    """x with A @ x = rhs through the Pallas factor-once ops: one
+    ``fused_newton_iter`` from k = 0 with fk = rhs commits
+    k - A^{-1}(k - fk) = A^{-1} rhs."""
+    b, f = rhs.shape
+    lu, perm = pi.batched_lu_factor(A, interpret=True)
+    x, _ = pi.fused_newton_iter(lu, perm, jnp.zeros_like(rhs), rhs, jnp.ones((b,), bool),
+                                jnp.ones((b, f), rhs.dtype), interpret=True)
+    return x
+
+
 class TestBatchedLinsolve:
-    """Newton linear-solve kernel vs the jnp.linalg.solve oracle.  Matrices
-    are I - dt*gamma*J-shaped (diagonally dominant), the regime the kernel is
-    specified for; agreement there is to 1e-6 in f32."""
+    """The Newton linear solve (LU factor + one substitution) vs the
+    jnp.linalg.solve oracle.  Matrices are I - dt*gamma*J-shaped (diagonally
+    dominant), the regime the kernels are specified for; agreement there is
+    to 1e-6 in f32."""
 
     @pytest.mark.parametrize("b,f", [(1, 1), (2, 3), (3, 8), (8, 128), (5, 37), (17, 130)])
     def test_matches_ref(self, b, f):
@@ -24,8 +37,8 @@ class TestBatchedLinsolve:
             np.eye(f) + (0.25 / np.sqrt(f)) * rng.standard_normal((b, f, f)), jnp.float32
         )
         rhs = jnp.asarray(rng.standard_normal((b, f)), jnp.float32)
-        r = ref.batched_linsolve(A, rhs)
-        p = pi.batched_linsolve(A, rhs, interpret=True)
+        r = jnp.linalg.solve(A, rhs[..., None])[..., 0]
+        p = _lu_solve(A, rhs)
         np.testing.assert_allclose(r, p, rtol=1e-4, atol=1e-5)
 
     def test_oracle_tight(self):
@@ -34,17 +47,17 @@ class TestBatchedLinsolve:
         b, f = 4, 6
         A = jnp.asarray(np.eye(f) + 0.1 * rng.standard_normal((b, f, f)), jnp.float32)
         rhs = jnp.asarray(rng.standard_normal((b, f)), jnp.float32)
-        r = ref.batched_linsolve(A, rhs)
-        p = pi.batched_linsolve(A, rhs, interpret=True)
+        r = jnp.linalg.solve(A, rhs[..., None])[..., 0]
+        p = _lu_solve(A, rhs)
         np.testing.assert_allclose(r, p, rtol=1e-6, atol=1e-6)
 
     def test_residual_is_small(self):
-        """The kernel's solution satisfies A @ x = rhs directly."""
+        """The kernels' solution satisfies A @ x = rhs directly."""
         rng = np.random.default_rng(3)
         b, f = 3, 20
         A = jnp.asarray(np.eye(f) + 0.1 * rng.standard_normal((b, f, f)), jnp.float32)
         rhs = jnp.asarray(rng.standard_normal((b, f)), jnp.float32)
-        x = pi.batched_linsolve(A, rhs, interpret=True)
+        x = _lu_solve(A, rhs)
         res = jnp.einsum("bij,bj->bi", A, x) - rhs
         np.testing.assert_allclose(np.asarray(res), 0.0, atol=2e-6)
 
@@ -52,7 +65,7 @@ class TestBatchedLinsolve:
         """A matrix needing row swaps (zero on the diagonal) still solves."""
         A = jnp.asarray([[[0.0, 1.0], [1.0, 0.0]]], jnp.float32)
         rhs = jnp.asarray([[2.0, 3.0]], jnp.float32)
-        x = pi.batched_linsolve(A, rhs, interpret=True)
+        x = _lu_solve(A, rhs)
         np.testing.assert_allclose(np.asarray(x), [[3.0, 2.0]], atol=1e-6)
 
 
@@ -76,29 +89,6 @@ class TestErrorNormToleranceShapes:
         r = ref.error_norm(err, y0, y1, atol, rtol)
         p = pi.error_norm(err, y0, y1, atol, rtol, interpret=True)
         np.testing.assert_allclose(r, p, rtol=1e-4, atol=1e-6)
-
-
-class TestMaskedNewtonUpdate:
-    @pytest.mark.parametrize("b,f", SHAPES)
-    def test_matches_ref(self, b, f):
-        rng = np.random.default_rng(b + 3 * f)
-        k, d = [jnp.asarray(rng.standard_normal((b, f)), jnp.float32) for _ in range(2)]
-        active = jnp.asarray(rng.uniform(size=(b,)) > 0.4)
-        scale = jnp.asarray(np.abs(rng.standard_normal((b, f))) + 0.3, jnp.float32)
-        rk, rn = ref.masked_newton_update(k, d, active, scale)
-        pk, pn = pi.masked_newton_update(k, d, active, scale, interpret=True)
-        np.testing.assert_allclose(rk, pk, rtol=1e-6, atol=1e-6)
-        np.testing.assert_allclose(rn, pn, rtol=1e-6, atol=1e-6)
-
-    def test_inactive_rows_frozen(self):
-        k = jnp.ones((3, 4))
-        d = jnp.full((3, 4), 0.5)
-        active = jnp.asarray([True, False, True])
-        pk, pn = pi.masked_newton_update(k, d, active, jnp.ones((3, 4)), interpret=True)
-        np.testing.assert_allclose(np.asarray(pk[1]), 1.0)
-        np.testing.assert_allclose(np.asarray(pk[0]), 0.5)
-        # the norm is reported for every row (callers mask by active)
-        np.testing.assert_allclose(np.asarray(pn), 0.5, rtol=1e-6)
 
 
 def _chord(rng, b, f):
@@ -299,3 +289,37 @@ class TestInstancesOnLanes:
         chunked = pi.fused_newton_iter(*op, k, fk, active, scale, interpret=True)
         for w, c in zip(whole, chunked):
             np.testing.assert_array_equal(np.asarray(w), np.asarray(c))
+
+
+@pytest.fixture
+def interpret_backend():
+    old = ops.backend()
+    ops.set_backend("interpret")
+    yield
+    ops.set_backend(old)
+
+
+class TestNewtonSolveFactorsM:
+    """``newton_solve(M=...)`` factors M on entry and iterates through the
+    Pallas substitution kernel: on an affine stage map ``k -> W k + c`` with
+    ``M = I - W`` the first iteration lands on the fixed point and the
+    second certifies it."""
+
+    @pytest.mark.parametrize("b,f", [(1, 1), (3, 5), (5, 37), (17, 130), (128, 8), (129, 3)])
+    def test_affine_map_converges_in_two(self, interpret_backend, b, f):
+        rng = np.random.default_rng(19 * b + f)
+        W = (0.25 / np.sqrt(f)) * rng.standard_normal((b, f, f))
+        c = rng.standard_normal((b, f))
+        Wj, cj = jnp.asarray(W, jnp.float32), jnp.asarray(c, jnp.float32)
+
+        def eval_fn(k):
+            return jnp.einsum("bij,bj->bi", Wj, k) + cj
+
+        M = jnp.eye(f, dtype=jnp.float32) - Wj
+        res = newton_solve(eval_fn, jnp.zeros((b, f), jnp.float32), M,
+                           jnp.ones((b, f), jnp.float32),
+                           config=NewtonConfig(tol=1e-5, max_iters=5))
+        assert np.all(np.asarray(res.converged))
+        assert np.all(np.asarray(res.n_iters) <= 2)
+        exact = np.linalg.solve(np.eye(f) - W, c[..., None])[..., 0]
+        np.testing.assert_allclose(np.asarray(res.k), exact, rtol=1e-5, atol=1e-5)

@@ -114,8 +114,6 @@ def _op_case(name, w):
             lambda c0, c1, c2, c3, x, m, out: op((c0, c1, c2, c3), x, m, out),
             (_f32(B, w),) * 4 + (_f32(B, N_EVAL), _bool(B, N_EVAL), _f32(B, N_EVAL, w)),
         )
-    if name == "masked_newton_update":
-        return op, (_f32(B, w), _f32(B, w), _bool(B), _f32(B, w))
     if name == "masked_bisect_refine":
         return (
             lambda c0, c1, c2, c3, lo, hi, vl, vm, a: op((c0, c1, c2, c3), lo, hi, vl, vm, a),
@@ -141,8 +139,6 @@ def _op_case(name, w):
             ),
             (_f32(B, w), _f32(B, w), col, col, col, col, _bool(B), col, col),
         )
-    if name == "batched_linsolve":
-        return op, (_f32(B, w, w), _f32(B, w))
     if name == "batched_lu_factor":
         return op, (_f32(B, w, w),)
     if name == "fused_newton_iter":
@@ -166,8 +162,8 @@ def _op_case(name, w):
 
 
 _F_OPS = ("stage_accum", "fused_update", "error_norm", "interp_eval",
-          "masked_newton_update", "masked_bisect_refine", "fused_step", "fused_step_poly")
-_N_OPS = ("batched_linsolve", "batched_lu_factor", "fused_newton_iter")
+          "masked_bisect_refine", "fused_step", "fused_step_poly")
+_N_OPS = ("batched_lu_factor", "fused_newton_iter")
 _E_OPS = ("fused_event_detect", "fused_event_commit")
 OP_CASES = (
     [(n, w) for n in _F_OPS for w in F_WIDTHS]
@@ -196,14 +192,15 @@ def _factor_op_specs(b, n):
 
 
 FACTOR_CASES = [(name, b, n) for name in ("batched_lu_factor", "fused_newton_iter")
-                for b in (8, 256) for n in N_WIDTHS]
+                for b in (8, 100, 256) for n in N_WIDTHS]
 
 
 @pytest.mark.parametrize("name,b,n", FACTOR_CASES,
                          ids=[f"{name}-b{b}-n{n}" for name, b, n in FACTOR_CASES])
 def test_factor_ops_compile_at_both_lane_blocks(one_chip, name, b, n):
-    """A batch below one lane tile is one full-extent instance block (8);
-    from 128 on, instances go in 128-lane blocks (256: two of them)."""
+    """A batch below one lane tile is one full-extent instance block (8, or
+    104 lanes for a batch of 100, the size of a served bucket); from 128
+    on, instances go in 128-lane blocks (256: two of them)."""
     _compile(getattr(pallas_impl, name), _factor_op_specs(b, n)[name], one_chip)
 
 
@@ -241,8 +238,7 @@ def test_dopri5_dense_solve_compiles_for_v5e(one_chip, pallas_backend, fused):
 @pytest.mark.parametrize("fused", [False, True])
 def test_kvaerno5_solve_compiles_for_v5e(one_chip, pallas_backend, fused):
     """Fused or not, a DIRK solve factors the chord matrix once per step
-    attempt and substitutes in every Newton iteration: no per-iteration
-    elimination (``_linsolve_kernel``) in the program."""
+    attempt and substitutes in every Newton iteration."""
     def solve(y0):
         return solve_ivp(allen_cahn, y0, None, t_start=0.0, t_end=1.0,
                          method="kvaerno5", rtol=1e-4, atol=1e-7,
@@ -253,7 +249,6 @@ def test_kvaerno5_solve_compiles_for_v5e(one_chip, pallas_backend, fused):
     # Pallas names each kernel after its function.
     kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
     assert {"_lu_factor_kernel", "_newton_iter_kernel"} <= kernels
-    assert not kernels & {"_linsolve_kernel", "_newton_update_kernel"}
 
 
 def _computations(hlo_text):
